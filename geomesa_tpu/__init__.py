@@ -29,7 +29,7 @@ JAX/XLA/Pallas on TPU:
 - ``security``— visibility expressions (parity with geomesa-security).
 - ``faults``  — fault-injection harness (named sites at every dependency
                 boundary, seeded replayable FaultPlans) + the recovery
-                fabric: typed error taxonomy, deadline-aware retry with
+                fabric: typed error classification, deadline-aware retry with
                 full-jitter backoff, per-dependency circuit breakers,
                 device-OOM host-eval fallback, poison-query quarantine,
                 and the ``gmtpu chaos`` invariant gate (no upstream

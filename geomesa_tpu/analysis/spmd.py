@@ -1036,7 +1036,7 @@ def gt24(mod: ModInfo, project) -> Iterator[Finding]:
     """Collective whose axis name no enclosing or calling-context wrap
     binds. Axis names that do not resolve statically (passed as
     parameters) are skipped — conservative, no false positives on
-    axis-generic helpers like jaxcompat.pcast."""
+    axis-generic helpers that wrap jax.lax.pcast."""
     idx = spmd_index(project)
     s = idx.by_relpath.get(mod.relpath)
     if s is None:

@@ -48,7 +48,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from geomesa_tpu.utils.jaxcompat import enable_x64 as _enable_x64
+from jax import enable_x64 as _enable_x64
 import numpy as np
 
 BBox = Tuple[float, float, float, float]
@@ -431,7 +431,7 @@ def density_zsparse_sharded(
     import jax.lax as lax
     from jax.sharding import PartitionSpec as P
 
-    from geomesa_tpu.utils.jaxcompat import shard_map
+    from jax import shard_map
 
     from geomesa_tpu.engine.density import density_grid
     from geomesa_tpu.parallel.mesh import SHARD_AXIS

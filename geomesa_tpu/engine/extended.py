@@ -43,7 +43,7 @@ from geomesa_tpu.engine.pip import (
     polygon_edges,
 )
 from geomesa_tpu.parallel.mesh import SHARD_AXIS
-from geomesa_tpu.utils.jaxcompat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 # must equal cql.hosteval._dist_to_segment_arrays_np's constant
 DEG_M = 111_194.9

@@ -41,7 +41,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from geomesa_tpu.utils.jaxcompat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from geomesa_tpu.engine.geodesy import EARTH_RADIUS_M, haversine_m
 from geomesa_tpu.utils.padding import next_pow2

@@ -33,8 +33,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from geomesa_tpu.utils.jaxcompat import pcast as _pcast
-from geomesa_tpu.utils.jaxcompat import shard_map as _shard_map
+from jax.lax import pcast as _pcast
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from geomesa_tpu.engine.geodesy import haversine_m
@@ -631,9 +631,7 @@ def knn_ring(
             P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS),
         ),
         out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
-        check_vma=False,  # fori_loop carry turns varying after step 1;
-        # the 0.4.x shard_map path relies on this (pcast shims to a
-        # no-op there — see jaxcompat.pcast)
+        check_vma=False,  # fori_loop carry turns varying after step 1
     )
     def run(qx, qy, dx, dy, mask):
         me = jax.lax.axis_index(SHARD_AXIS)

@@ -32,7 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from geomesa_tpu.utils.jaxcompat import enable_x64 as _enable_x64
+from jax import enable_x64 as _enable_x64
 import numpy as np
 
 POINT_TILE = 512

@@ -48,7 +48,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from geomesa_tpu.utils.jaxcompat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 import numpy as np
 
 BBox = Tuple[float, float, float, float]

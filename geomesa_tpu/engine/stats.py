@@ -16,7 +16,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from geomesa_tpu.utils.jaxcompat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from geomesa_tpu.parallel.mesh import SHARD_AXIS

@@ -22,7 +22,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from geomesa_tpu.utils.jaxcompat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -129,6 +129,27 @@ def tube_select(
 
     _, hits = jax.lax.scan(data_block, None, (xd, yd, td))
     return hits.reshape(-1)[:n] & mask
+
+
+def tube_select_host(x, y, t, tube_x, tube_y, tube_t, radius_m,
+                     half_window_ms) -> np.ndarray:
+    """f64 host oracle of `tube_select` (no mask): bool [N], a point
+    matches when its haversine distance to ANY tube sample is within
+    that sample's radius and its time within the sample's half window.
+    Loops over the T samples, so memory stays O(N)."""
+    from geomesa_tpu.engine.geodesy import haversine_m_np
+
+    x, y = np.asarray(x, np.float64), np.asarray(y, np.float64)
+    t = np.asarray(t, np.int64)
+    n_t = len(tube_x)
+    radius = np.broadcast_to(np.asarray(radius_m, np.float64), (n_t,))
+    window = np.broadcast_to(np.asarray(half_window_ms, np.int64), (n_t,))
+    hits = np.zeros(len(x), bool)
+    for i in range(n_t):
+        near = haversine_m_np(x, y, float(tube_x[i]), float(tube_y[i]))
+        hits |= (near <= radius[i]) & (
+            np.abs(t - np.int64(tube_t[i])) <= window[i])
+    return hits
 
 
 # tube samples per pruning segment: a long track's segment boxes must

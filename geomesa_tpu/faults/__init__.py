@@ -9,7 +9,7 @@ Two halves (docs/ROBUSTNESS.md):
    inactive.
 2. **Recovery fabric** (`errors.py`, `retry.py`, `breaker.py`,
    `quarantine.py`, `context.py`): typed transient/permanent/OOM
-   taxonomy, bounded deadline-aware retry with full-jitter backoff,
+   classification, bounded deadline-aware retry with full-jitter backoff,
    per-dependency circuit breakers, poison-query quarantine, and the
    RecoveryMeter that attributes retries/faults to ServeEvents.
 

@@ -12,7 +12,7 @@ replays the run and diffs the fire logs to prove it.
 Invariants asserted (the acceptance contract, docs/ROBUSTNESS.md):
 
   1. zero un-typed escapes: every request resolves with a result or an
-     error the taxonomy recognizes (QueryRejected / QueryTimeout /
+     error the classification recognizes (QueryRejected / QueryTimeout /
      BreakerOpen / OSError-family / FaultInjected ...);
   2. zero torn manifests: after the run, metadata.json parses and every
      entry references an existing data file with a matching row count;
@@ -177,7 +177,7 @@ def _run_workload(plan: FaultPlan, root: str, requests: int,
         try:
             fn()
             report.ok += 1
-        except Exception as e:  # noqa: BLE001 — the taxonomy decides
+        except Exception as e:  # noqa: BLE001 — the classification decides
             if _errors.is_typed(e):
                 key = type(e).__name__
                 report.typed_errors[key] = (
@@ -295,7 +295,7 @@ def _pipeline_burst(plan: FaultPlan, root: str, report: ChaosReport,
                     f.result(timeout=120)
                     ok += 1
                     report.ok += 1
-                except Exception as e:  # noqa: BLE001 — taxonomy decides
+                except Exception as e:  # noqa: BLE001 — classification decides
                     if _errors.is_typed(e):
                         typed += 1
                         key = type(e).__name__
@@ -438,7 +438,7 @@ def _subscribe_phase(plan: FaultPlan, report: ChaosReport,
                 report.invariant_failures.append(
                     "subscribe phase: injected kafka.poll outage did not "
                     "surface from the poll")
-            except Exception as e:  # noqa: BLE001 — the taxonomy decides
+            except Exception as e:  # noqa: BLE001 — the classification decides
                 # typed errors are recorded but NOT counted ok — same
                 # accounting as outcome() and the pipeline burst
                 if _errors.is_typed(e):
@@ -499,8 +499,8 @@ def _subscribe_phase(plan: FaultPlan, report: ChaosReport,
 # over every local device, mesh residency on). Each window's only
 # device.transfer call is its staged query upload, so window 3's
 # transfer faulted through all 3 retry attempts = in-harness calls
-# 3, 4, 5 at the site — modelling one shard's host->device tunnel
-# dropping mid-window.
+# 3, 4, 5 at the site — modelling one shard's host->device transfer
+# failing mid-window.
 _MESH_REQUESTS = 6
 _MESH_FAULT_CALLS = (3, 4, 5)
 
@@ -553,7 +553,7 @@ def _mesh_phase(plan: FaultPlan, root: str, report: ChaosReport,
                     f.result(timeout=120)
                     ok += 1
                     report.ok += 1
-                except Exception as e:  # noqa: BLE001 — taxonomy decides
+                except Exception as e:  # noqa: BLE001 — classification decides
                     if _errors.is_typed(e):
                         typed += 1
                         key = type(e).__name__

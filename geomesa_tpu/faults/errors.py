@@ -1,4 +1,4 @@
-"""Typed transient/permanent error taxonomy for the recovery fabric.
+"""Typed transient/permanent error classification for the recovery fabric.
 
 Every dependency boundary (storage, Kafka, device transfer, kvstore,
 compile cache) classifies failures into three kinds:

@@ -27,7 +27,9 @@ until the fresh incarnation passes its warmup gate and takes traffic.
 from __future__ import annotations
 
 import dataclasses
+import glob
 import json
+import os
 import subprocess
 import sys
 import threading
@@ -37,6 +39,45 @@ from typing import Callable, List, Optional
 from geomesa_tpu.fleet.membership import Membership, ReplicaHandle
 from geomesa_tpu.fleet.replica import ReplicaServer
 from geomesa_tpu.fleet.router import FleetRouter
+
+
+class ChipPlacementError(ValueError):
+    """Process spawn on a TPU host asked for more replicas than the host
+    has chips. A chip belongs to one process: an extra replica could
+    only hang or fail on its first device use."""
+
+
+GOOGLE_PCI_VENDOR = "0x1ae0"
+
+
+def _google_device(sysfs_dir: str) -> bool:
+    try:
+        with open(os.path.join(sysfs_dir, "vendor")) as f:
+            return f.read().strip().lower() == GOOGLE_PCI_VENDOR
+    except OSError:
+        return False
+
+
+def local_tpu_chips(dev: str = "/dev", sysfs: str = "/sys") -> int:
+    """TPU chips this host's replicas would open, counted from their
+    device files without opening them: a supervisor that opened the chip
+    would keep it from every replica. A chip is an `accelN` or a VFIO
+    group whose PCI vendor is Google; other accelerators and VFIO
+    passthrough count for nothing. 0 when JAX_PLATFORMS leaves the TPU
+    out, since the replicas then never touch a chip."""
+    platforms = os.environ.get("JAX_PLATFORMS", "").lower()
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    accel = [p for p in glob.glob(os.path.join(dev, "accel[0-9]*"))
+             if _google_device(os.path.join(
+                 sysfs, "class", "accel", os.path.basename(p), "device"))]
+    if accel:
+        return len(accel)
+    return sum(
+        any(_google_device(d) for d in glob.glob(os.path.join(
+            sysfs, "kernel", "iommu_groups", os.path.basename(p),
+            "devices", "*")))
+        for p in glob.glob(os.path.join(dev, "vfio", "[0-9]*")))
 
 
 @dataclasses.dataclass
@@ -87,10 +128,18 @@ class FleetSupervisor:
             supervisor=self, rehome=config.rehome)
         self._slots = 0
         self._lock = threading.Lock()
+        # process replicas on a TPU host get one chip each (slot i ->
+        # chip i); 0 = no chips to place (CPU host, CPU-pinned workers
+        # or thread spawn)
+        self._chips = (
+            local_tpu_chips()
+            if config.spawn == "process" and not config.force_cpu_workers
+            else 0)
 
     # -- lifecycle ---------------------------------------------------------
 
     def start(self, wait_ready: bool = True) -> int:
+        self._check_chip(self.config.n_replicas - 1)
         port = self.router.start()
         for _ in range(self.config.n_replicas):
             self.spawn_replica()
@@ -121,7 +170,7 @@ class FleetSupervisor:
         if self.config.spawn == "thread":
             handle = self._spawn_thread(rid)
         else:
-            handle = self._spawn_process(rid)
+            handle = self._spawn_process(rid, slot)
         handle.slot = slot
         handle.incarnation = incarnation
         self.membership.add(handle)
@@ -151,7 +200,15 @@ class FleetSupervisor:
             replica_id=rid, host=self.config.host, port=port,
             spawn="thread", server=server)
 
-    def _spawn_process(self, rid: str) -> ReplicaHandle:
+    def _check_chip(self, slot: int) -> None:
+        if self._chips and slot >= self._chips:
+            raise ChipPlacementError(
+                f"replica slot {slot} needs chip {slot}, but this host "
+                f"has {self._chips} TPU chip(s): run at most "
+                f"{self._chips} process replicas here, or spawn threads")
+
+    def _spawn_process(self, rid: str, slot: int) -> ReplicaHandle:
+        self._check_chip(slot)
         cmd = [sys.executable, "-m", "geomesa_tpu.fleet.replica",
                "--catalog", self.config.catalog,
                "--replica-id", rid,
@@ -162,9 +219,15 @@ class FleetSupervisor:
             cmd += ["--metrics-port", str(self.config.metrics_port)]
         if self.config.force_cpu_workers:
             cmd += ["--force-cpu"]
+        env = None
+        if self._chips:
+            # this replica's own chip, as a one-chip slice
+            env = dict(os.environ, TPU_VISIBLE_CHIPS=str(slot),
+                       TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                       TPU_PROCESS_BOUNDS="1,1,1")
         proc = subprocess.Popen(
             cmd, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-            text=True)
+            text=True, env=env)
         # spawn contract (parallel/launch.py discipline): the child's
         # FIRST stdout line reports its ephemeral port
         line = proc.stdout.readline()

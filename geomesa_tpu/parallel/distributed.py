@@ -125,7 +125,7 @@ def assert_uniform_runtime(mesh=None) -> None:
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from geomesa_tpu.utils.jaxcompat import shard_map as _shard_map
+    from jax import shard_map as _shard_map
 
     mesh = mesh if mesh is not None else global_mesh()
     fp = runtime_fingerprint()

@@ -268,8 +268,13 @@ class FileSystemStorage:
         # it, so counts only ever move at batch boundaries (the serve
         # torn-read contract, tests/test_serve_concurrency.py)
         staged = []
-        for name in np.unique(names):
-            sub = batch.select(names == name)
+        uniq, inv = np.unique(names, return_inverse=True)
+        inv = inv.reshape(-1)
+        # rows grouped by partition, in batch order within each
+        order = np.argsort(inv, kind="stable")
+        ends = np.cumsum(np.bincount(inv, minlength=len(uniq)))
+        for name, lo, hi in zip(uniq, np.r_[0, ends[:-1]], ends):
+            sub = batch.select(order[lo:hi])
             pdir = os.path.join(self.root, name)
             os.makedirs(pdir, exist_ok=True)
             fname = f"{uuid.uuid4().hex}.{self.encoding}"

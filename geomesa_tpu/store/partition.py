@@ -25,7 +25,7 @@ from geomesa_tpu.curve.xz import XZ2SFC
 
 
 class PartitionScheme:
-    def partitions_for(self, batch: FeatureBatch) -> List[str]:
+    def partitions_for(self, batch: FeatureBatch) -> Sequence[str]:
         """Partition name per feature (len == len(batch))."""
         raise NotImplementedError
 
@@ -69,16 +69,24 @@ class DateTimeScheme(PartitionScheme):
                 f"one of {sorted(_DT_PATTERNS)}"
             )
 
-    def _format(self, millis: np.ndarray) -> List[str]:
+    def _format(self, millis: np.ndarray) -> np.ndarray:
+        """Partition name per timestamp. Each distinct bucket is formatted
+        once: a batch spans few buckets and many rows."""
         import datetime as _dt
 
         fmt = _DT_PATTERNS[self.pattern]
-        return [
-            _dt.datetime.fromtimestamp(int(m) / 1000, _dt.timezone.utc).strftime(fmt)
-            for m in np.asarray(millis, np.int64)
-        ]
+        buckets = np.asarray(millis, np.int64).astype("datetime64[ms]") \
+            .astype(f"datetime64[{_STEP[self.pattern]}]")
+        uniq, inv = np.unique(buckets, return_inverse=True)
+        names = np.array([
+            _dt.datetime.fromtimestamp(
+                int(b.astype("datetime64[ms]").astype(np.int64)) / 1000,
+                _dt.timezone.utc).strftime(fmt)
+            for b in uniq
+        ], dtype=str)
+        return names[inv.reshape(-1)]
 
-    def partitions_for(self, batch: FeatureBatch) -> List[str]:
+    def partitions_for(self, batch: FeatureBatch) -> np.ndarray:
         return self._format(batch.columns[self.dtg_attr])
 
     def prune(self, bbox: BBox, interval: Interval) -> Optional[Set[str]]:

@@ -618,7 +618,7 @@ class DeltaEvaluator:
             else:
                 consumed = self._fold(st, subs, version, changed,
                                       removed, cleared)
-        except Exception as e:  # noqa: BLE001 — taxonomy + retry contract
+        except Exception as e:  # noqa: BLE001 — classification + retry contract
             # infrastructure failure (device transfer, injected
             # subscribe.eval fault): NOTHING was applied — keep the
             # buffer so the next poll retries the whole window

@@ -102,8 +102,8 @@ class SystemProperties:
         "geomesa.compile.cache.dir", "", str,
         "persistent XLA compilation-cache directory shared by the "
         "planner, QueryService, gmtpu serve and bench (empty = "
-        "~/.cache/geomesa_tpu/jax_cache, with a per-backend subdir; "
-        "'off' disables)",
+        "$JAX_COMPILATION_CACHE_DIR if set, else <checkout>/.jax_cache "
+        "with a per-backend subdir; 'off' disables)",
     )
     LOAD_INTERCEPTORS = SystemProperty(
         "geomesa.query.interceptors.load", False,

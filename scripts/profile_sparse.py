@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from geomesa_tpu.engine.knn_scan import knn_fullscan, knn_sparse_scan
-from scripts._util import RTT, sync, timeit
+from scripts._util import sync, timeit
 
 
 def morton(x, y):
@@ -90,7 +90,7 @@ def main():
     print(f"  {time.perf_counter()-s:.0f}s; overflow={bool(out[3])}",
           flush=True)
     t1 = timeit(lambda: sync(fused_sparse(dx, dy, dt, dspeed, dqx, dqy)[1]))
-    print(f"sparse latency:  {t1*1e3:7.1f} ms (net {(t1-RTT)*1e3:5.0f}) "
+    print(f"sparse latency:  {t1*1e3:7.1f} ms "
           f"-> {n/t1/1e6:.0f}M pts/s", flush=True)
 
     R = 8
@@ -111,7 +111,7 @@ def main():
     sync(out[1])
     print(f"  {time.perf_counter()-s:.0f}s", flush=True)
     t2 = timeit(lambda: sync(fused_dense(dx, dy, dt, dspeed, dqx, dqy)[1]))
-    print(f"dense latency:   {t2*1e3:7.1f} ms (net {(t2-RTT)*1e3:5.0f}) "
+    print(f"dense latency:   {t2*1e3:7.1f} ms "
           f"-> {n/t2/1e6:.0f}M pts/s", flush=True)
 
     # recall parity vs numpy oracle

@@ -138,7 +138,7 @@ class TestGT24UnboundCollective:
         assert not active(fs)
 
     def test_clean_parameter_axis_skipped(self, tmp_path):
-        # axis-generic helpers (jaxcompat.pcast shape) stay silent
+        # axis-generic helpers (pcast-wrapper shape) stay silent
         fs = lint_tree(tmp_path, {"geomesa_tpu/parallel/ops.py": """\
             from jax import lax
 

@@ -46,6 +46,12 @@ RADIUS_M = 150_000.0
 HALF_WINDOW_MS = 12 * 3_600_000
 T = 17  # pads to 32: one tube ring class for every window below
 
+# these specify ROADMAP 2.1/2.2, which no code implements yet: extended
+# mesh residency, kNN over non-point stores, the served tube verb
+NOT_BUILT = pytest.mark.xfail(
+    strict=True, raises=(AttributeError, ValueError),
+    reason="specified, not implemented (ROADMAP 2.1/2.2)")
+
 
 def _day_millis(day: str) -> int:
     return int(np.datetime64(day, "ms").astype(np.int64))
@@ -137,6 +143,7 @@ def _tube_names(svc, started=False) -> list:
     return sorted(r.features.columns["name"].decode())
 
 
+@NOT_BUILT
 def test_extended_mesh_residency_csr_tiles(mesh_store):
     """The extended superbatch row-shards across the mesh AND carries
     per-shard CSR tiles with shard-local offsets; the partition
@@ -188,6 +195,7 @@ def test_counts_bit_identical_across_routes(mesh_store, serial_store):
     assert got_dw == want_dw
 
 
+@NOT_BUILT
 def test_knn_on_lines_bit_identical(mesh_store, serial_store):
     """kNN over an extended store runs on the representative coords —
     mesh route bit-identical to single-chip serial."""
@@ -218,6 +226,7 @@ def tube_oracle(host_batch) -> list:
     return sorted(names[i] for i in np.nonzero(hits)[0])
 
 
+@NOT_BUILT
 def test_tube_parity_16_windows_all_routes(mesh_store, serial_store,
                                            host_batch):
     """TubeSelect bit-identical to the f64 host oracle on every route,
@@ -263,6 +272,7 @@ def test_tube_parity_16_windows_all_routes(mesh_store, serial_store,
     assert _counter("serve.ring.windows") - base_ring >= 15
 
 
+@NOT_BUILT
 def test_tube_coalesced_window_one_dispatch(mesh_store, host_batch):
     """>= 8 identical concurrent TubeSelect requests coalesce (dedup
     key) into ONE window and ONE device dispatch: service counter says
@@ -315,6 +325,7 @@ def test_tube_coalesced_window_one_dispatch(mesh_store, host_batch):
         assert got == want
 
 
+@NOT_BUILT
 def test_tube_ring_retires_non_point_refusal(mesh_store):
     """The extended tier's whole point on the ring: tube windows ARM
     (no `non_point`/`no_geometry` refusal), and the per-reason

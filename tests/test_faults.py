@@ -57,10 +57,10 @@ def _pristine_fabric():
     faults.BREAKERS.reset()
 
 
-# -- error taxonomy ---------------------------------------------------------
+# -- error classification ---------------------------------------------------------
 
 
-class TestTaxonomy:
+class TestClassification:
     def test_classification(self):
         from geomesa_tpu.plan.planner import QueryTimeout
 

@@ -147,9 +147,17 @@ class TestRouting:
             got = cli.request({"id": "s", "op": "stats"})
             assert got["ok"] and got["stats"]["replica"]["state"] == \
                 "ready"
-            snap = sup.stats()
+            # the router counts a send after it returns, which can be
+            # after the reply already reached this client
+            deadline = time.monotonic() + 10.0
+            while True:
+                snap = sup.stats()
+                routed = sum(r["routed"] for r in snap["replicas"])
+                if routed >= 10 or time.monotonic() > deadline:
+                    break
+                time.sleep(0.01)
             assert snap["router"]["requests"] >= 10
-            assert sum(r["routed"] for r in snap["replicas"]) >= 10
+            assert routed >= 10
             cli.close()
         finally:
             sup.close()
@@ -542,6 +550,75 @@ class TestMetricsPort:
         finally:
             for r in reps:
                 r.stop()
+
+
+class TestChipPlacement:
+    """Process replicas on a TPU host: one chip each, and a typed
+    refusal, before anything starts, for replicas beyond the chips."""
+
+    @pytest.fixture()
+    def chips(self, monkeypatch):
+        from geomesa_tpu.fleet import supervisor
+
+        def stub(n):
+            monkeypatch.setattr(supervisor, "local_tpu_chips", lambda: n)
+
+        return stub
+
+    def test_more_replicas_than_chips_refused_at_start(self, catalog,
+                                                       chips):
+        from geomesa_tpu.fleet.supervisor import ChipPlacementError
+
+        chips(1)
+        sup = FleetSupervisor(FleetConfig(
+            n_replicas=2, catalog=catalog, spawn="process"))
+        with pytest.raises(ChipPlacementError, match="1 TPU chip"):
+            sup.start()
+        assert sup.membership.all() == []
+        assert sup.router._listener is None  # nothing started
+
+    @pytest.mark.parametrize("spawn,force_cpu", [
+        ("thread", False), ("process", True)])
+    def test_no_chip_placement_off_the_chip(self, catalog, chips, spawn,
+                                            force_cpu):
+        chips(1)
+        sup = FleetSupervisor(FleetConfig(
+            n_replicas=2, catalog=catalog, spawn=spawn,
+            force_cpu_workers=force_cpu))
+        sup._check_chip(1)  # no refusal: these replicas hold no chip
+
+    @pytest.mark.parametrize("devices,platforms,want", [
+        ([("accel", "0x1ae0"), ("accel", "0x1ae0")], None, 2),
+        ([("accel", "0x1ae0"), ("accel", "0x8086")], "tpu", 1),
+        ([("vfio", "0x1ae0")] * 4, None, 4),
+        # GPU / NIC passthrough: VFIO groups that are not TPUs
+        ([("vfio", "0x10de"), ("vfio", "0x8086")], None, 0),
+        ([("vfio", "0x1ae0")] * 4, "cpu", 0),
+        ([], None, 0),
+    ])
+    def test_chip_detection(self, tmp_path, monkeypatch, devices,
+                            platforms, want):
+        """local_tpu_chips against a fake /dev and /sys tree."""
+        from geomesa_tpu.fleet.supervisor import local_tpu_chips
+
+        dev, sysfs = tmp_path / "dev", tmp_path / "sys"
+        (dev / "vfio").mkdir(parents=True)
+        (dev / "vfio" / "vfio").touch()  # the VFIO control node
+        for i, (kind, vendor) in enumerate(devices):
+            if kind == "accel":
+                (dev / f"accel{i}").touch()
+                d = sysfs / "class" / "accel" / f"accel{i}" / "device"
+            else:
+                (dev / "vfio" / str(i)).touch()
+                d = (sysfs / "kernel" / "iommu_groups" / str(i)
+                     / "devices" / f"0000:00:0{i}.0")
+            d.mkdir(parents=True)
+            (d / "vendor").write_text(vendor + "\n")
+        if platforms is None:
+            monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        else:
+            monkeypatch.setenv("JAX_PLATFORMS", platforms)
+        assert local_tpu_chips(str(dev), str(sysfs)) == want
 
 
 @pytest.mark.slow

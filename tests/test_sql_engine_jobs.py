@@ -732,8 +732,8 @@ class TestSqlHaving:
 
 
 class TestSqlJoinVariants:
-    """Round-3 surface: multi-table chains, LEFT/RIGHT OUTER, DISTINCT
-    (VERDICT.md round-2 task 6)."""
+    """Round-3 surface: multi-table chains, LEFT/RIGHT OUTER,
+    DISTINCT."""
 
     def _three_tables(self, tmp_path):
         rng = np.random.default_rng(37)
