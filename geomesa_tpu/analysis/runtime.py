@@ -82,9 +82,9 @@ class JitTracker:
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
             before = fn._cache_size()
-            t0 = time.perf_counter()
+            t0 = time.perf_counter_ns()
             out = fn(*args, **kwargs)
-            elapsed = time.perf_counter() - t0
+            elapsed = (time.perf_counter_ns() - t0) / 1e9
             grew = fn._cache_size() - before
             storm_count = 0
             with self._lock:
@@ -112,7 +112,7 @@ class JitTracker:
                 try:
                     from geomesa_tpu.compilecache.stall import STALLS
 
-                    STALLS.note(label, elapsed)
+                    STALLS.note(label, elapsed, start_ns=t0)
                 except Exception:
                     pass
             if recorder is not None and origin is not None:

@@ -306,9 +306,7 @@ def register(sub: "argparse._SubParsersAction") -> None:
                                "dispatches_per_window ratio — the "
                                "persistent serve loop's headline "
                                "(docs/SERVING.md \"Persistent serve "
-                               "loop\"); with --record-baseline the "
-                               "ring.dispatch.* sentinel family is "
-                               "recorded too")
+                               "loop\")")
     bserve_p.add_argument("--no-ring", action="store_true",
                           help="disable the persistent serve loop for "
                                "the measured run (ring programs are "
@@ -347,22 +345,6 @@ def register(sub: "argparse._SubParsersAction") -> None:
                                "Perfetto trace_event JSON here; also "
                                "prints the dispatch-gap report line "
                                "(docs/OBSERVABILITY.md)")
-    bserve_p.add_argument("--record-baseline", default=None,
-                          metavar="PATH", nargs="?",
-                          const="BASELINE_SERVE.json",
-                          help="record the measured run's profile as a "
-                               "sentinel baseline (default path "
-                               "BASELINE_SERVE.json; docs/"
-                               "OBSERVABILITY.md \"Sentinel\")")
-    bserve_p.add_argument("--sentinel", default=None, metavar="PATH",
-                          nargs="?", const="BASELINE_SERVE.json",
-                          help="compare the measured run against a "
-                               "sentinel baseline; exit nonzero on a "
-                               "regressed verdict")
-    bserve_p.add_argument("--sentinel-threshold", type=float,
-                          default=None, metavar="RATIO",
-                          help="sentinel median-ratio threshold "
-                               "(default 1.5)")
     bserve_p.set_defaults(func=_bench_serve)
 
     prof_p = sub.add_parser(
@@ -381,45 +363,6 @@ def register(sub: "argparse._SubParsersAction") -> None:
     prof_p.add_argument("--json", action="store_true",
                         help="machine output instead of text")
     prof_p.set_defaults(func=_prof)
-
-    sentinel_p = sub.add_parser(
-        "sentinel", help="perf-regression sentinel: compare a serve "
-                         "profile against a committed baseline; typed "
-                         "per-metric verdicts (ok/regressed/improved/"
-                         "insufficient-data), nonzero exit on "
-                         "regression")
-    sentinel_p.add_argument("--baseline", "-b", required=True,
-                            help="baseline JSON (bench-serve "
-                                 "--record-baseline)")
-    sentinel_p.add_argument("--input", "-i", default=None,
-                            metavar="JSON",
-                            help="current profile: a saved /debug/prof "
-                                 "document (default: poll --url/"
-                                 "--host/--port live)")
-    sentinel_p.add_argument("--url", default=None,
-                            help="live endpoint base URL")
-    sentinel_p.add_argument("--host", default="127.0.0.1")
-    sentinel_p.add_argument("--port", type=int, default=9090)
-    sentinel_p.add_argument("--threshold", type=float, default=None,
-                            help="median-ratio regression threshold "
-                                 "(default 1.5)")
-    sentinel_p.add_argument("--min-overlap", type=float, default=None,
-                            help="distribution-overlap floor below "
-                                 "which a shifted median counts "
-                                 "(default 0.2)")
-    sentinel_p.add_argument("--min-n", type=int, default=None,
-                            help="samples required per side before any "
-                                 "verdict but insufficient-data "
-                                 "(default 8)")
-    sentinel_p.add_argument("--strict", action="store_true",
-                            help="also exit nonzero on any "
-                                 "insufficient-data verdict (a metric "
-                                 "that stopped being comparable — "
-                                 "renamed phase, lost instrumentation "
-                                 "— must not read as green)")
-    sentinel_p.add_argument("--json", action="store_true",
-                            help="machine output instead of text")
-    sentinel_p.set_defaults(func=_sentinel)
 
     # telemetry surface (docs/OBSERVABILITY.md)
     top_p = sub.add_parser(
@@ -741,23 +684,13 @@ def _bench_serve(args) -> int:
         warm.close()
 
         tracing = getattr(args, "trace", None)
-        record_baseline = getattr(args, "record_baseline", None)
-        sentinel_path = getattr(args, "sentinel", None)
-        profiling = record_baseline or sentinel_path
-        if tracing or profiling:
+        if tracing:
             # trace only the measured runs (warmup spans would pollute
-            # the gap attribution with deliberate cold-path compiles);
-            # the sentinel paths additionally fold them into a fresh
-            # profiler window so the baseline is THIS run's
+            # the gap attribution with deliberate cold-path compiles)
             from geomesa_tpu.telemetry import RECORDER, TRACER
 
             RECORDER.clear()
             TRACER.enable()
-        if profiling:
-            from geomesa_tpu.telemetry.prof import PROFILER
-
-            PROFILER.reset()
-            PROFILER.enable()
 
         try:
             store_points = store.get_feature_source(
@@ -810,21 +743,11 @@ def _bench_serve(args) -> int:
         coalesced = run("coalesced", ServeConfig(
             max_batch=args.max_batch, max_wait_ms=args.max_wait_ms,
             pipeline=pipe, ring=ring_on, mesh=mesh_spec))
-        profile_doc = None
-        if profiling:
-            # snapshot (and stop) the profiler NOW: the serial/single-
-            # chip comparison runs below are deliberately slower and
-            # must not fold into the measured profile
-            from geomesa_tpu.telemetry.prof import PROFILER
-
-            profile_doc = PROFILER.snapshot(include_samples=True)
-            PROFILER.disable()
         if getattr(args, "ring", False) and args.mode == "sustained":
             # the persistent-serve-loop headline (docs/SERVING.md
             # "Persistent serve loop"): identical sustained workload,
             # ring OFF — per-window dispatch count must be strictly
-            # higher there. Runs after the profiler snapshot like the
-            # serial comparison (the baseline is deliberately slower)
+            # higher there
             ringless = run("pipelined_baseline", ServeConfig(
                 max_batch=args.max_batch, max_wait_ms=args.max_wait_ms,
                 pipeline=pipe, ring=False, mesh=mesh_spec))
@@ -907,47 +830,6 @@ def _bench_serve(args) -> int:
                 "run": "gap", "perfetto": tracing,
                 "traces_recorded": rec["trace_count"],
                 **gap_report(traces)}))
-        if profiling:
-            from geomesa_tpu.telemetry import TRACER
-            from geomesa_tpu.telemetry import sentinel as snt
-
-            if not tracing:
-                TRACER.disable()
-            extra_samples = {}
-            if coalesced.dispatches_per_window > 0:
-                # ring.dispatch.*: the per-window dispatch count is a
-                # deterministic structural constant, replicated to the
-                # run's window count so the sentinel's min_n gate
-                # applies — a ring regression (e.g. silently falling
-                # back to the pipelined 4-op shape) moves the whole
-                # vector and fails the median-ratio comparison
-                wins = max(int(coalesced.pipelined_windows
-                               or coalesced.dispatches or 1), 1)
-                extra_samples["ring.dispatch.per_window"] = (
-                    [coalesced.dispatches_per_window] * min(wins, 64))
-            doc = snt.baseline_from_profile(
-                profile_doc, latency_samples_ms=coalesced.samples_ms,
-                extra_samples=extra_samples,
-                extra={"mode": args.mode, "n": args.n,
-                       "kind": args.kind,
-                       "ring_windows": coalesced.ring_windows,
-                       "throughput_qps": round(
-                           coalesced.throughput_qps, 2)})
-            if record_baseline:
-                path = snt.save_baseline(record_baseline, doc)
-                print(json.dumps({"run": "baseline", "path": path,
-                                  "metrics": len(doc["metrics"])}))
-            if sentinel_path:
-                baseline = snt.load_baseline(sentinel_path)
-                kw = {}
-                if getattr(args, "sentinel_threshold", None):
-                    kw["threshold"] = args.sentinel_threshold
-                report = snt.compare(baseline, doc, **kw)
-                print(json.dumps({"run": "sentinel",
-                                  "baseline": sentinel_path,
-                                  **report}))
-                print(snt.render_verdicts(report), file=sys.stderr)
-                return snt.exit_code(report)
     return 0
 
 
@@ -1131,9 +1013,7 @@ def _bench_wire(args) -> int:
     bytes/s, encode p50/p99) plus the PushMux fan-out (events/s at
     --push-sinks subscribers, one encode per frame asserted). The
     verdict gates on decoded-parity, the >=5x rows/s acceptance
-    floor, and the one-encode invariant; with --record-baseline /
-    --sentinel the wire.encode.* sample families ride the sentinel so
-    a slowed encoder fails CI like any other hot-path regression."""
+    floor, and the one-encode invariant."""
     import contextlib
     import tempfile
 
@@ -1189,35 +1069,6 @@ def _bench_wire(args) -> int:
             "parity": rep.wire_parity_ok,
             "one_encode": rep.push_encodes == rep.push_frames,
             "push_events_per_s": round(rep.push_events_per_s)}))
-        record_baseline = getattr(args, "record_baseline", None)
-        sentinel_path = getattr(args, "sentinel", None)
-        if record_baseline or sentinel_path:
-            from geomesa_tpu.telemetry import sentinel as snt
-
-            doc = snt.baseline_from_profile(
-                {},
-                extra_samples={
-                    "wire.encode.json": rep.wire_json_samples_ms,
-                    "wire.encode.columnar": rep.wire_columnar_samples_ms,
-                    "wire.push.publish": rep.push_publish_samples_ms,
-                },
-                extra={"mode": "wire", "rows": rep.wire_rows,
-                       "push_sinks": rep.push_sinks,
-                       "speedup": round(rep.wire_speedup, 2)})
-            if record_baseline:
-                path = snt.save_baseline(record_baseline, doc)
-                print(json.dumps({"run": "baseline", "path": path,
-                                  "metrics": len(doc["metrics"])}))
-            if sentinel_path:
-                baseline = snt.load_baseline(sentinel_path)
-                kw = {}
-                if getattr(args, "sentinel_threshold", None):
-                    kw["threshold"] = args.sentinel_threshold
-                report = snt.compare(baseline, doc, **kw)
-                print(json.dumps({"run": "sentinel",
-                                  "baseline": sentinel_path, **report}))
-                print(snt.render_verdicts(report), file=sys.stderr)
-                return max(snt.exit_code(report), 0 if ok else 1)
         return 0 if ok else 1
 
 
@@ -1275,17 +1126,6 @@ def _bench_approx(args) -> int:
         # outside the measured window
         exact_counts = {c: int(planner.count(Query(type_name, c)))
                         for c in cqls}
-        record_baseline = getattr(args, "record_baseline", None)
-        sentinel_path = getattr(args, "sentinel", None)
-        profiling = record_baseline or sentinel_path
-        if profiling:
-            from geomesa_tpu.telemetry import RECORDER, TRACER
-            from geomesa_tpu.telemetry.prof import PROFILER
-
-            RECORDER.clear()
-            TRACER.enable()
-            PROFILER.reset()
-            PROFILER.enable()
         svc = QueryService(store, ServeConfig(
             max_wait_ms=args.max_wait_ms, result_cache=0))
         try:
@@ -1318,41 +1158,6 @@ def _bench_approx(args) -> int:
             "tiers": {"sketch": rep.tier_sketch,
                       "cached": rep.tier_cached,
                       "exact": rep.tier_exact}}))
-        if profiling:
-            from geomesa_tpu.telemetry import TRACER
-            from geomesa_tpu.telemetry import sentinel as snt
-            from geomesa_tpu.telemetry.prof import PROFILER
-
-            profile_doc = PROFILER.snapshot(include_samples=True)
-            PROFILER.disable()
-            TRACER.disable()
-            doc = snt.baseline_from_profile(
-                profile_doc, latency_samples_ms=rep.samples_ms,
-                extra_samples={
-                    "approx.count.sketch": rep.approx_samples_ms,
-                    "approx.count.exact": rep.exact_samples_ms,
-                },
-                extra={"mode": "approx", "n": args.n,
-                       "tolerance": args.tolerance,
-                       "speedup_p50": round(rep.approx_speedup_p50, 2)})
-            if record_baseline:
-                path = snt.save_baseline(record_baseline, doc)
-                print(json.dumps({"run": "baseline", "path": path,
-                                  "metrics": len(doc["metrics"])}))
-            if sentinel_path:
-                baseline = snt.load_baseline(sentinel_path)
-                kw = {}
-                if getattr(args, "sentinel_threshold", None):
-                    kw["threshold"] = args.sentinel_threshold
-                report = snt.compare(baseline, doc, **kw)
-                print(json.dumps({"run": "sentinel",
-                                  "baseline": sentinel_path, **report}))
-                print(snt.render_verdicts(report), file=sys.stderr)
-                # the correctness verdict (bound violations / tier
-                # shares / cache pass) gates the exit alongside the
-                # latency sentinel: a bound-violating build must fail
-                # CI even when the distributions look fine
-                return max(snt.exit_code(report), 0 if ok else 1)
         return 0 if ok else 1
 
 
@@ -1431,6 +1236,13 @@ def _top_frame(doc: dict, prev, dt) -> str:
         f"   stalled dispatches "
         f"{serve.get('compile_stalled_dispatches', 0)}",
     ]
+    prev_serve = (prev or {}).get("serve", {})
+    d_loop = serve.get("loop_ns", 0) - prev_serve.get("loop_ns", 0)
+    if prev is not None and d_loop > 0:
+        d_wait = (serve.get("loop_wait_ns", 0)
+                  - prev_serve.get("loop_wait_ns", 0))
+        lines.append(f"  dispatcher busy {100.0 * (1 - d_wait / d_loop):5.1f}%"
+                     " (1 - queue wait / loop time, since last poll)")
     breakers = doc.get("breakers", {})
     open_b = {k: v for k, v in sorted(breakers.items()) if v != "closed"}
     lines.append(
@@ -1555,43 +1367,6 @@ def _prof(args) -> int:
         return 2
     print(json.dumps(doc) if args.json else render_prof(doc))
     return 0
-
-
-def _sentinel(args) -> int:
-    """Compare a serve profile against a committed baseline; exit
-    nonzero on a regressed verdict (docs/OBSERVABILITY.md
-    "Sentinel")."""
-    import urllib.error
-
-    from geomesa_tpu.telemetry import sentinel as snt
-
-    baseline = snt.load_baseline(args.baseline)
-    if args.input:
-        with open(args.input) as f:
-            profile = json.load(f)
-    else:
-        base = args.url or f"http://{args.host}:{args.port}"
-        try:
-            profile = _fetch_json(base, "/debug/prof")
-        except (urllib.error.URLError, OSError, ValueError) as e:
-            print(f"gmtpu sentinel: cannot poll {base}/debug/prof: {e}",
-                  file=sys.stderr)
-            return 1
-    if "metrics" in profile and "phases" not in profile:
-        current = profile  # already a baseline-shaped metric table
-    else:
-        current = snt.baseline_from_profile(profile)
-    kw = {}
-    if args.threshold is not None:
-        kw["threshold"] = args.threshold
-    if args.min_overlap is not None:
-        kw["min_overlap"] = args.min_overlap
-    if args.min_n is not None:
-        kw["min_n"] = args.min_n
-    report = snt.compare(baseline, current, **kw)
-    print(json.dumps(report) if args.json
-          else snt.render_verdicts(report))
-    return snt.exit_code(report, strict=getattr(args, "strict", False))
 
 
 def _trace(args) -> int:

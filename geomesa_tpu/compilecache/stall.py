@@ -19,13 +19,17 @@ from __future__ import annotations
 import collections
 import contextlib
 import threading
+from time import perf_counter_ns
 from typing import List, Optional, Tuple
 
 _MAX_LOG = 4096
 
 
 class StallMeter:
-    """Thread-safe bounded log of (seq, thread, label, seconds) stalls.
+    """Thread-safe bounded log of (seq, thread, label, start_ns,
+    seconds) stalls. `start_ns` is the `perf_counter_ns` at which the
+    compiling call began, so a trace can place the stall where it
+    happened inside its dispatch window.
 
     Entries carry the noting thread's ident so a reader can scope its
     window to its own thread — the serve dispatch loop does, which keeps
@@ -38,7 +42,7 @@ class StallMeter:
     def __init__(self, max_log: int = _MAX_LOG):
         self._lock = threading.Lock()
         self._seq = 0
-        self._log: "collections.deque[Tuple[int, int, str, float]]" = (
+        self._log: "collections.deque[Tuple[int, int, str, int, float]]" = (
             collections.deque(maxlen=max_log))
         self._tls = threading.local()
 
@@ -54,13 +58,19 @@ class StallMeter:
         finally:
             self._tls.suppress = prev
 
-    def note(self, label: str, seconds: float) -> None:
+    def note(self, label: str, seconds: float,
+             start_ns: Optional[int] = None) -> None:
+        """Log one stall of `seconds` that began at `start_ns`
+        (`perf_counter_ns`; default: `seconds` before now, for a caller
+        noting right as its compiling call returns)."""
         if getattr(self._tls, "suppress", False):
             return
+        if start_ns is None:
+            start_ns = perf_counter_ns() - int(seconds * 1e9)
         with self._lock:
             self._seq += 1
             self._log.append((self._seq, threading.get_ident(),
-                              label, seconds))
+                              label, int(start_ns), seconds))
         try:
             from geomesa_tpu.utils.metrics import metrics
 
@@ -80,15 +90,17 @@ class StallMeter:
         with self._lock:
             return self._seq
 
-    def since(self, token: int,
-              thread_ident: Optional[int] = None) -> List[Tuple[str, float]]:
-        """Stalls noted after `token`; with `thread_ident`, only those
-        noted by that thread (per-dispatch attribution: the dispatch's
-        own synchronous work runs on the dispatch thread)."""
+    def since(self, token: int, thread_ident: Optional[int] = None
+              ) -> List[Tuple[str, int, float]]:
+        """(label, start_ns, seconds) of the stalls noted after `token`;
+        with `thread_ident`, only those noted by that thread
+        (per-dispatch attribution: the dispatch's own synchronous work
+        runs on the dispatch thread)."""
         with self._lock:
             if self._seq == token:  # steady state: no stalls since the
                 return []           # token — O(1) on the dispatch path
-            return [(label, secs) for seq, tid, label, secs in self._log
+            return [(label, t0, secs)
+                    for seq, tid, label, t0, secs in self._log
                     if seq > token
                     and (thread_ident is None or tid == thread_ident)]
 

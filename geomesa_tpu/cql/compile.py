@@ -40,6 +40,7 @@ from geomesa_tpu.cql import ast
 from geomesa_tpu.engine.device import VALID, DeviceBatch
 from geomesa_tpu.engine.geodesy import haversine_m, point_to_segments_m
 from geomesa_tpu.engine.pip import points_in_polygon, polygon_edges
+from geomesa_tpu.telemetry.trace import TRACER, device_get
 
 ParamBuilder = Callable[[FeatureBatch], np.ndarray]
 
@@ -87,16 +88,17 @@ class CompiledFilter:
         we only keep it when the cache grew)."""
         before = (jit_fn._cache_size()
                   if hasattr(jit_fn, "_cache_size") else -1)
-        t0 = time.perf_counter()
+        t0 = time.perf_counter_ns()
         out = jit_fn(*args)
         if before >= 0 and jit_fn._cache_size() > before:
-            dt = time.perf_counter() - t0
+            dt = (time.perf_counter_ns() - t0) / 1e9
             try:
                 from geomesa_tpu.compilecache.stall import STALLS
                 from geomesa_tpu.utils.metrics import metrics
 
                 metrics.histogram("plan.filter.compile").update(dt)
-                STALLS.note(f"filter:{which}:{self.cql[:64]}", dt)
+                STALLS.note(f"filter:{which}:{self.cql[:64]}", dt,
+                            start_ns=t0)
             except Exception:
                 pass  # observability must never fail the query
         return out
@@ -124,15 +126,12 @@ class CompiledFilter:
         No-op when the filter has no polygon predicate."""
         if self._band_jit is None or self.filter_ast is None:
             return mask
-        flags = np.asarray(self.band(dev, batch))
+        flags = device_get(self.band(dev, batch))
         idx = np.nonzero(flags)[0]
         if not len(idx):
             return mask
-        from geomesa_tpu.cql.hosteval import eval_filter_host
-
-        sub = batch.select(idx)
         mask = mask.copy()
-        mask[idx] = eval_filter_host(self.filter_ast, sub)
+        mask[idx] = self._eval_band_rows(batch, idx)
         return mask
 
     def mask_refined(self, dev: DeviceBatch, batch: FeatureBatch) -> np.ndarray:
@@ -151,7 +150,7 @@ class CompiledFilter:
         m = self.mask(dev, batch)
         if extra is not None:
             m = m & extra
-        total = int(np.asarray(jnp.sum(m, dtype=jnp.int64)))
+        total = int(device_get(jnp.sum(m, dtype=jnp.int64)))
         return total + self.band_count_correction(dev, batch, m, extra)
 
     def band_count_correction(
@@ -172,11 +171,15 @@ class CompiledFilter:
         idx, approx = self._band_rows(params, dev, extra, len(batch))
         if not len(idx):
             return 0
+        return int(self._eval_band_rows(batch, idx).sum()) - approx
+
+    def _eval_band_rows(self, batch: FeatureBatch, idx: np.ndarray):
+        """The host f64 evaluation of the band rows `idx` (a
+        `band.refine` span: host work, not a device wait)."""
         from geomesa_tpu.cql.hosteval import eval_filter_host
 
-        exact = int(eval_filter_host(self.filter_ast,
-                                     batch.select(idx)).sum())
-        return exact - approx
+        with TRACER.span("band.refine", rows=int(len(idx))):
+            return eval_filter_host(self.filter_ast, batch.select(idx))
 
     def _band_rows(self, params, dev, extra, nrows: int):
         """ONE fused dispatch: (band-row indices, approximate in-mask
@@ -187,8 +190,9 @@ class CompiledFilter:
         separate count round trip."""
         k = 64
         while True:
-            idx, approx = jax.device_get(
-                self._cx_gather(params, dev, extra, k=k))
+            with TRACER.span("kernel.dispatch", kernel="band.rows"):
+                out = self._cx_gather(params, dev, extra, k=k)
+            idx, approx = device_get(out)
             idx = idx[idx < nrows].astype(np.int64)
             if len(idx) < k or k >= nrows:
                 return idx, int(approx)
@@ -265,11 +269,7 @@ class CompiledFilter:
         idx, _ = self._band_rows(params, dev, None, len(batch))
         if not len(idx):
             return empty
-        from geomesa_tpu.cql.hosteval import eval_filter_host
-
-        exact = np.asarray(
-            eval_filter_host(self.filter_ast, batch.select(idx)), bool)
-        return idx, exact
+        return idx, np.asarray(self._eval_band_rows(batch, idx), bool)
 
     def mask_fn(self):
         """The raw pure function (params, dev) -> mask, for fusion into

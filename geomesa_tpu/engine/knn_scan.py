@@ -580,17 +580,21 @@ def knn_sparse_finish(
     m_blocks: int = 64,
     interpret: bool = False,
     extra=(),
+    get=None,
 ) -> tuple:
     """Sync half: ONE transfer for results + overflow flag (+ any
     `extra` device values riding the same fetch — the serve path's fused
     count scalar), falling back to the dense fullscan on overflow
-    exactly like `knn_sparse_auto`. Returns
+    exactly like `knn_sparse_auto`. `get` performs each blocking read
+    (default `jax.device_get`; the serve path passes its traced
+    `telemetry.trace.device_get`). Returns
     (dists np, idx np, capacity_used, extra_host tuple)."""
+    get = get or jax.device_get
     # ONE transfer: fetching ov alone first would serialize a second
     # device round trip before the caller's own result fetch
-    fd, fi, ov, *extra_host = jax.device_get((fd, fi, ov) + tuple(extra))
+    fd, fi, ov, *extra_host = get((fd, fi, ov) + tuple(extra))
     if bool(ov):
-        fd, fi = jax.device_get(knn_fullscan(
+        fd, fi = get(knn_fullscan(
             qx, qy, x, y, mask, k=k, m_blocks=m_blocks,
             interpret=interpret))
         return fd, fi, -1, tuple(extra_host)

@@ -62,8 +62,7 @@ class LoadReport:
     # how many windows rode a ring program, how many fell back typed,
     # and the per-window device-interaction count (`serve.device.ops`
     # delta / windows) — the number `bench-serve --mode sustained
-    # --ring` compares against the pipelined baseline and the
-    # `ring.dispatch.*` sentinel family gates
+    # --ring` compares against the pipelined baseline
     ring_windows: int = 0
     ring_fallbacks: int = 0
     dispatches_per_window: float = 0.0
@@ -97,17 +96,6 @@ class LoadReport:
     tier_cached: int = 0
     tier_exact: int = 0
     bound_violations: int = 0
-    # sentinel input (telemetry/sentinel.py): a bounded sample of the
-    # raw end-to-end latencies, so `bench-serve --record-baseline` can
-    # commit a DISTRIBUTION (median + overlap comparison) instead of
-    # the point percentiles above. Evenly strided from the sorted
-    # samples — order statistics, not a random subsample, so two runs
-    # of the same workload produce comparable vectors.
-    samples_ms: List[float] = dataclasses.field(default_factory=list)
-    # approx mode: per-tier latency sample vectors for the sentinel's
-    # approx.* reservoir families (a regressed sketch path fails CI)
-    approx_samples_ms: List[float] = dataclasses.field(default_factory=list)
-    exact_samples_ms: List[float] = dataclasses.field(default_factory=list)
     # wire mode (docs/SERVING.md "Columnar wire"): JSON-lines vs
     # columnar record-batch encode throughput over identical results,
     # plus the push fan-out (frames x sinks through the one-encode
@@ -128,21 +116,9 @@ class LoadReport:
     push_encodes: int = 0
     push_events_per_s: float = 0.0
     wire_parity_ok: bool = True
-    wire_json_samples_ms: List[float] = dataclasses.field(
-        default_factory=list)
-    wire_columnar_samples_ms: List[float] = dataclasses.field(
-        default_factory=list)
-    push_publish_samples_ms: List[float] = dataclasses.field(
-        default_factory=list)
 
     def to_json(self) -> dict:
         doc = dataclasses.asdict(self)
-        doc.pop("samples_ms", None)  # report lines stay readable
-        doc.pop("approx_samples_ms", None)
-        doc.pop("exact_samples_ms", None)
-        doc.pop("wire_json_samples_ms", None)
-        doc.pop("wire_columnar_samples_ms", None)
-        doc.pop("push_publish_samples_ms", None)
         if self.mode != "approx":
             for k in ("approx_ok", "exact_ok", "approx_p50_ms",
                       "approx_p99_ms", "exact_p50_ms", "exact_p99_ms",
@@ -196,8 +172,6 @@ def _report(mode: str, duration: float, lat_s: List[float], sent: int,
     def q(p):
         return float(np.percentile(lat, p)) if ok else 0.0
 
-    sorted_lat = np.sort(lat)
-    stride = max(1, ok // 512)
     return LoadReport(
         mode=mode,
         duration_s=duration,
@@ -212,7 +186,6 @@ def _report(mode: str, duration: float, lat_s: List[float], sent: int,
         max_ms=float(lat.max()) if ok else 0.0,
         dispatches=stats.get("dispatches", 0),
         coalesced=stats.get("coalesced", 0),
-        samples_ms=[round(float(v), 4) for v in sorted_lat[::stride]],
     )
 
 
@@ -767,13 +740,10 @@ def run_wire(
     sinks = [mux.register(sink_write, mode=colwire.WIRE_JSON,
                           threaded=False) for _ in range(push_sinks)]
     fids = [f"bench-f{i}" for i in range(push_fids)]
-    p_ms = []
     t0 = time.monotonic()
     for i in range(push_frames):
-        f0 = time.monotonic()
         mux.publish({"event": "enter", "subscription": "bench-sub",
                      "seq": i + 1, "fids": fids}, sinks)
-        p_ms.append((time.monotonic() - f0) * 1000.0)
     push_wall = max(time.monotonic() - t0, 1e-9)
     mux_stats = mux.stats()
     mux.close()
@@ -797,9 +767,6 @@ def run_wire(
     rep.push_encodes = mux_stats["encodes"]
     rep.push_events_per_s = push_frames * push_sinks / push_wall
     rep.wire_parity_ok = parity_ok
-    rep.wire_json_samples_ms = sorted(j_ms)
-    rep.wire_columnar_samples_ms = sorted(c_ms)
-    rep.push_publish_samples_ms = sorted(p_ms)
     return rep
 
 
@@ -918,11 +885,6 @@ def run_approx(
     rep.tier_cached = tiers.get("cached", 0) - base_tiers.get("cached", 0)
     rep.tier_exact = tiers.get("exact", 0) - base_tiers.get("exact", 0)
     rep.bound_violations = violations[0]
-    for arr, dest in ((approx_lat, rep.approx_samples_ms),
-                      (exact_lat, rep.exact_samples_ms)):
-        s = np.sort(np.asarray(arr, np.float64) * 1000.0)
-        stride = max(1, len(s) // 512)
-        dest.extend(round(float(v), 4) for v in s[::stride])
     return rep
 
 

@@ -73,7 +73,7 @@ class PipelinedWindow:
     __slots__ = ("source", "live", "counts", "lead", "t0", "g0_ns",
                  "adopt_from", "wid", "running", "running_counts",
                  "qx", "qy", "offsets", "staged", "launch",
-                 "stalls", "recovery", "seq", "prep_start_ns")
+                 "stalls", "recovery", "seq", "prep_start_ns", "put_ns")
 
     def __init__(self, source, live, counts, lead, t0, g0_ns,
                  adopt_from, seq):
@@ -93,6 +93,7 @@ class PipelinedWindow:
         self.stalls: list = []
         self.recovery: list = []
         self.prep_start_ns = 0
+        self.put_ns = 0             # perf_counter_ns at the completer hand-off
 
 
 class DispatchPipeline:
@@ -194,27 +195,38 @@ class DispatchPipeline:
 
     def submit(self, source, live: List[ServeRequest],
                counts: List[ServeRequest], lead, t0: float, g0_ns: int,
-               adopt_from: int) -> None:
+               g1_ns: int, adopt_from: int) -> None:
         """Run prepare/transfer/launch for one window and hand it to the
         completer. Blocks while `depth` windows are in flight. All
         failure modes resolve member futures and complete the window's
         bookkeeping before returning — the caller never needs to clean
-        up."""
+        up. `g0_ns`/`g1_ns` bound the gather (the `coalesce` span); the
+        dispatcher's work from the gather's end to the slot wait
+        (expiry split, fused-count drain, queue-wait stamps, source
+        lookup) is the window's `window.open` span."""
         from geomesa_tpu.compilecache.stall import STALLS
         from geomesa_tpu.faults import RECOVERY
 
         self._ensure_started()
-        # bounded-wait acquire: the completer survives every window
-        # error by construction, but if it is ever not running (process
-        # teardown, BaseException) the dispatch thread must fail loudly
-        # instead of wedging on a slot that can never free
-        while not self._slots.acquire(timeout=1.0):
-            with self._lock:
-                worker = self._worker
-            if worker is None or not worker.is_alive():
-                raise RuntimeError(
-                    "pipeline completer is not running; window slots "
-                    "cannot free")
+        # the slot wait: zero-length when a slot is free at once, else
+        # the time the dispatch thread stood behind `depth` windows in
+        # flight (recorded below as `pipeline.slot_wait` once the window
+        # has its span id)
+        wait0_ns = wait1_ns = perf_counter_ns()
+        if not self._slots.acquire(blocking=False):
+            # bounded-wait acquire: the completer survives every window
+            # error by construction, but if it is ever not running
+            # (process teardown, BaseException) the dispatch thread must
+            # fail loudly instead of wedging on a slot that can never
+            # free
+            while not self._slots.acquire(timeout=1.0):
+                with self._lock:
+                    worker = self._worker
+                if worker is None or not worker.is_alive():
+                    raise RuntimeError(
+                        "pipeline completer is not running; window "
+                        "slots cannot free")
+            wait1_ns = perf_counter_ns()
         with self._lock:
             self._seq += 1
             self._inflight += 1
@@ -225,6 +237,10 @@ class DispatchPipeline:
         trace = lead.trace
         if trace is not None:
             win.wid = new_span_id()
+            trace.record("window.open", g1_ns, wait0_ns,
+                         parent_id=win.wid)
+            trace.record("pipeline.slot_wait", wait0_ns, wait1_ns,
+                         parent_id=win.wid)
         stall_token = STALLS.token()
         rec_token = RECOVERY.token()
         ok = False
@@ -268,6 +284,7 @@ class DispatchPipeline:
                 "service closed before the pipelined window synced"))
             self._window_done(win)
             return
+        win.put_ns = perf_counter_ns()
         self._completions.put(win)
 
     def _note_meters(self, win, stall_token, rec_token) -> None:
@@ -356,6 +373,12 @@ class DispatchPipeline:
             win = self._completions.get()
             if win is _STOP:
                 return
+            trace = win.lead.trace
+            if trace is not None and win.wid is not None:
+                # the window's wait in the queue while the completer
+                # synced the windows ahead of it
+                trace.record("pipeline.pending", win.put_ns,
+                             perf_counter_ns(), parent_id=win.wid)
             try:
                 self._sync(win)
             except Exception as e:  # noqa: BLE001 — the completer must live
@@ -465,7 +488,11 @@ class DispatchPipeline:
         only known now), hand the window to the service's shared finish
         path, free the slot. Called exactly once per submitted window —
         from submit's failure paths or from the completer — so the slot
-        frees exactly once per acquire."""
+        frees exactly once per acquire. The finish path itself is the
+        lead trace's `window.finish` span (attr `window`: the window's
+        span id), a child of the root since the window span has
+        already ended; it runs up to the slot release, so the next
+        window's slot wait shows what the bookkeeping held back."""
         import logging
 
         t1 = time.monotonic()
@@ -491,6 +518,9 @@ class DispatchPipeline:
                     "serve pipeline finish error")
                 RECORDER.crash_dump("serve pipeline finish error", e)
         finally:
+            if trace is not None and win.wid is not None:
+                trace.record("window.finish", end_ns, perf_counter_ns(),
+                             window=win.wid)
             with self._lock:
                 self._inflight -= 1
             self._slots.release()

@@ -786,7 +786,7 @@ def serve_connection(
                             doc.update(fields)
                             if req.trace is not None:
                                 # the encode span feeds the profiler's
-                                # phase.wire.encode sentinel family
+                                # phase.wire.encode reservoir
                                 req.trace.record(
                                     "wire.encode", e0_ns,
                                     perf_counter_ns(), kind=req.kind)

@@ -30,7 +30,7 @@ window the Python work is ONLY
 — no plan, no residency walk, no mask recompute, no tracing, no new
 executions compiled (zero recompiles asserted via JitTracker in
 tests/test_ringloop.py). `dispatches_per_window` (bench-serve
-`--mode sustained --ring`, sentinel family `ring.dispatch.*`) meters
+`--mode sustained --ring`) meters
 exactly this: the ring route is strictly below the pipelined baseline
 on CPU CI, and on real TPU it is the structure BENCH r06 needs to hit
 sustained ≥700M pts/s.
@@ -122,20 +122,20 @@ class RingLoop:
         pipelined transfer+launch path. Raises only what a pipelined
         launch could raise (fault-injected slot transfers included):
         the caller's failure ladder applies unchanged."""
-        from geomesa_tpu.serve.batcher import ring_key
+        from geomesa_tpu.serve.batcher import (
+            batch_timeout_ms, note_launch_route, ring_key)
 
         lead = win.lead
-        key = ring_key(lead, len(win.qx))
-        if key is None:
-            return False
-        prog = self._current_program(key, win)
-        if prog is None:
-            return False
-        from geomesa_tpu.serve.batcher import (
-            batch_timeout_ms, note_launch_route)
-
-        timeout_ms = batch_timeout_ms(win.running + win.running_counts)
         with TRACER.scope(lead.trace, parent_id=win.wid):
+            # the window class's key and its armed program (armed here
+            # on first use: the arm's plan/mask spans nest under it)
+            with TRACER.span("ring.program"):
+                key = ring_key(lead, len(win.qx))
+                prog = (self._current_program(key, win)
+                        if key is not None else None)
+            if prog is None:
+                return False
+            timeout_ms = batch_timeout_ms(win.running + win.running_counts)
             with TRACER.span("ring.slot", q=int(len(win.qx)),
                              depth=self.depth):
                 win.staged = self._stager.stage(

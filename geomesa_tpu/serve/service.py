@@ -232,6 +232,12 @@ class QueryService:
         self._inflight = 0
         self._state_lock = threading.Lock()
         self._counters: Dict[str, int] = {}
+        # dispatcher load, always on: cumulative ns the dispatch loop
+        # has run, and the part of it spent waiting inside queue.pop.
+        # Plain ints written only by the dispatch thread (no lock on
+        # the hot path); stats() reads them as they stand
+        self._loop_ns = 0
+        self._loop_wait_ns = 0
         self._worker: Optional[threading.Thread] = None
         # standing-query manager (geomesa_tpu.subscribe): attached by
         # the wire layer when the first subscribe verb arrives, so
@@ -832,8 +838,18 @@ class QueryService:
     def _loop(self) -> None:
         import logging
 
+        pc = time.perf_counter_ns
+        start = pc()
+        base = self._loop_ns
         while True:
+            t = pc()
             req = self.queue.pop(timeout=0.05, on_pop=self._mark_inflight)
+            t2 = pc()
+            # gt: waive GT07
+            # (single writer: only this thread writes the two load
+            # counters; stats() reads each int whole under the GIL)
+            self._loop_wait_ns += t2 - t
+            self._loop_ns = base + t2 - start
             if req is None:
                 if self._stop.is_set():
                     return
@@ -972,7 +988,7 @@ class QueryService:
                         r.future.set_exception(e)
                 self._finish_window(live, counts, lead, t0,
                                     time.monotonic(), adopt_from,
-                                    None, 0, 0, [], [], pipelined=True)
+                                    None, 0, [], [], pipelined=True)
                 return
             # the window stays in flight past this method: it owns one
             # inflight token until _window_complete releases it, so
@@ -981,7 +997,7 @@ class QueryService:
                 self._inflight += 1
             try:
                 self.pipeline.submit(source, live, counts, lead, t0,
-                                     g0_ns, adopt_from)
+                                     g0_ns, g1_ns, adopt_from)
             except BaseException as e:
                 # submit resolves all futures on its internal failure
                 # paths; an exception HERE means the window never got a
@@ -998,7 +1014,6 @@ class QueryService:
         stall_token = STALLS.token()
         rec_token = RECOVERY.token()
         dispatch_span_id = None
-        dispatch_start_ns = 0
         dispatch_end_ns = 0
         if lead.trace is not None:
             with TRACER.scope(lead.trace):
@@ -1008,7 +1023,6 @@ class QueryService:
                 # scope's shared handle holds the just-closed span);
                 # None if tracing flipped off between admit and here
                 dispatch_span_id = getattr(dsp, "span_id", None)
-                dispatch_start_ns = getattr(dsp, "start_ns", 0)
                 dispatch_end_ns = getattr(dsp, "end_ns", 0)
         else:
             self._run_window(live)
@@ -1030,8 +1044,8 @@ class QueryService:
         recovery = RECOVERY.since(rec_token,
                                   thread_ident=threading.get_ident())
         self._finish_window(live, [], lead, t0, t1, adopt_from,
-                            dispatch_span_id, dispatch_start_ns,
-                            dispatch_end_ns, stalls, recovery)
+                            dispatch_span_id, dispatch_end_ns, stalls,
+                            recovery)
 
     def _window_complete(self, win, t1: float, end_ns: int) -> None:
         """Pipeline completion callback (completer thread): shared
@@ -1039,16 +1053,15 @@ class QueryService:
         try:
             self._finish_window(
                 win.live, win.counts, win.lead, win.t0, t1,
-                win.adopt_from, win.wid, win.g0_ns, end_ns,
+                win.adopt_from, win.wid, end_ns,
                 win.stalls, win.recovery, pipelined=True)
         finally:
             with self._state_lock:
                 self._inflight -= 1
 
     def _finish_window(self, live, counts, lead, t0, t1, adopt_from,
-                       dispatch_span_id, dispatch_start_ns,
-                       dispatch_end_ns, stalls, recovery,
-                       pipelined: bool = False) -> None:
+                       dispatch_span_id, dispatch_end_ns, stalls,
+                       recovery, pipelined: bool = False) -> None:
         """Everything that happens after a window's futures are
         resolved: stall/recovery attribution spans, counters, metrics,
         quarantine accounting, rider trace adoption, audit events.
@@ -1063,23 +1076,22 @@ class QueryService:
             f"{name}={state}"
             for name, state in sorted(BREAKERS.states().items())
             if state != "closed")
-        compile_ms = sum(s for _, s in stalls) * 1000.0
-        labels = list(dict.fromkeys(lbl for lbl, _ in stalls))
+        compile_ms = sum(s for _, _, s in stalls) * 1000.0
+        labels = list(dict.fromkeys(lbl for lbl, _, _ in stalls))
         compiled = ",".join(labels[:5])
         if len(labels) > 5:
             compiled += f",+{len(labels) - 5}"
         if lead.trace is not None and dispatch_span_id is not None:
             # stall/recovery attribution as child spans of the dispatch
-            # window (the meters only know durations, not start times:
-            # stalls render right-aligned at the window end, marked
-            # synthetic; retry/fault notes render as instants)
-            for label, secs in stalls:
-                dur_ns = int(secs * 1e9)
+            # window: each stall at the interval its compiling call
+            # took (the meter keeps the start stamp); retry/fault notes
+            # only know when they were read, and render as instants at
+            # the window end
+            for label, start_ns, secs in stalls:
                 lead.trace.record(
-                    "compile.stall",
-                    max(dispatch_end_ns - dur_ns, dispatch_start_ns),
-                    dispatch_end_ns, parent_id=dispatch_span_id,
-                    label=label, synthetic_ts=True)
+                    "compile.stall", start_ns,
+                    start_ns + int(secs * 1e9),
+                    parent_id=dispatch_span_id, label=label)
             for kind, label in recovery:
                 lead.trace.record(
                     kind, dispatch_end_ns, dispatch_end_ns,
@@ -1275,6 +1287,10 @@ class QueryService:
             out = dict(self._counters)
         out.setdefault("dispatches", 0)
         out.setdefault("coalesced", 0)
+        # dispatcher load: busy share over an interval is
+        # 1 - Δloop_wait_ns / Δloop_ns
+        out["loop_ns"] = self._loop_ns
+        out["loop_wait_ns"] = self._loop_wait_ns
         out["queue_depth"] = len(self.queue)
         out["degrade_level"] = self.degrade_level()
         out["quarantine"] = self.quarantine.stats()

@@ -21,10 +21,6 @@ Pieces:
 - `prof.py`: the always-on continuous profiler (`gmtpu prof`,
   `/debug/prof`) — reservoir-sampled per-phase/per-kernel/per-shard
   distributions folded from every recorded trace at bounded cost.
-- `sentinel.py`: the perf-regression sentinel (`gmtpu sentinel`,
-  `bench-serve --sentinel/--record-baseline`) — noise-tolerant
-  baseline comparison with typed per-metric verdicts and a nonzero
-  exit on regression.
 """
 
 from geomesa_tpu.telemetry.export import (MetricsServer, from_perfetto,

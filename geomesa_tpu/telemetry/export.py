@@ -236,8 +236,7 @@ class MetricsServer:
             from geomesa_tpu.telemetry.prof import PROFILER
 
             # samples ride along (bounded: <= 256 per reservoir) so a
-            # saved /debug/prof document is directly comparable by the
-            # sentinel's distribution-overlap test
+            # saved /debug/prof document keeps whole distributions
             doc = PROFILER.snapshot(include_samples=True)
             return (200, "application/json", json.dumps(doc).encode())
         return (404, "text/plain", b"not found\n")

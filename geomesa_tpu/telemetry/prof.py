@@ -307,7 +307,7 @@ class ContinuousProfiler:
     # -- reporting ---------------------------------------------------------
 
     def snapshot(self, include_samples: bool = False) -> dict:
-        """The /debug/prof document (and the sentinel's input)."""
+        """The /debug/prof document."""
         with self._lock:
             phases = {n: r.snapshot(include_samples)
                       for n, r in sorted(self._phases.items())}

@@ -38,6 +38,22 @@ Design constraints, in priority order:
 Cross-thread phases (queue wait spans the submitting thread and the
 dispatch thread) are recorded retroactively via `Trace.record` with
 explicit timestamps.
+
+**One clock with the device trace.** While tracing is on, every live
+span (`TRACER.span` on a scoped trace) is also issued as a profiler
+`TraceMe` of the same name, carrying the span id as its `sid` stat.
+A running `jax.profiler` trace then holds the program's spans on its
+host plane, on the profiler's clock, beside the device ops; each
+span's `perf_counter_ns` start against its TraceMe start gives one
+clock offset per span. With no profiler session the TraceMe is a
+~0.3µs no-op. `Trace.record` spans (stamped after the fact) have no
+TraceMe.
+
+**Blocking device reads.** Every blocking device read of the served
+path goes through `device_get`, which holds it in a `device.sync`
+span — the span is the read and nothing else, so the host work around
+a read (result canonicalization, f64 band refinement) lands in spans
+of its own.
 """
 
 from __future__ import annotations
@@ -49,7 +65,7 @@ from time import perf_counter_ns
 from typing import List, Optional
 
 __all__ = ["Span", "Trace", "Tracer", "TRACER", "NOOP_SPAN",
-           "new_span_id"]
+           "new_span_id", "device_get"]
 
 _span_ids = itertools.count(1)
 _trace_ids = itertools.count(1)
@@ -244,9 +260,10 @@ class _SpanHandle:
     allocation alone eats a third of the 2µs budget.
 
     How it works: `Tracer.span()` pushes an *open frame*
-    `[name, span_id, parent_id, start_ns, attrs]` onto the scope's
-    frame stack and returns this shared handle; `__exit__` pops the top
-    frame, stamps the end time and appends the completed storage tuple.
+    `[name, span_id, parent_id, start_ns, attrs, traceme]` onto the
+    scope's frame stack and returns this shared handle; `__exit__` pops
+    the top frame, stamps the end time, closes the profiler mirror and
+    appends the completed storage tuple.
     Correct because with-blocks are strictly LIFO per thread — the
     frame `__exit__` pops is always the one the matching `span()` call
     pushed (ExitStack unwinds in reverse order, preserving LIFO). The
@@ -278,7 +295,9 @@ class _SpanHandle:
     def __exit__(self, exc_type, exc, tb, _pc=perf_counter_ns) -> bool:
         end_ns = _pc()
         ctx = self._ctx
-        name, span_id, parent_id, start_ns, attrs = ctx[2].pop()
+        name, span_id, parent_id, start_ns, attrs, tm = ctx[2].pop()
+        if tm is not None:
+            tm.__exit__(None, None, None)
         if exc_type is not None:
             if attrs is None:
                 attrs = {}
@@ -335,13 +354,17 @@ class Tracer:
     (`TRACER`) serves the whole process; QueryServices, the planner and
     the engine all open spans through it."""
 
-    __slots__ = ("enabled", "_tls")
+    __slots__ = ("enabled", "_tls", "_traceme")
 
     def __init__(self):
         self.enabled = False
         self._tls = threading.local()
+        self._traceme = None
 
     def enable(self) -> None:
+        """Turn tracing on; live spans are mirrored as profiler TraceMes
+        (module doc)."""
+        self._traceme = _traceme_type()
         self.enabled = True
 
     def disable(self) -> None:
@@ -386,8 +409,32 @@ class Tracer:
             return _noop
         stack = ctx[2]
         parent_id = stack[-1][1] if stack else ctx[1]
-        stack.append([name, _next_id(), parent_id, _pc(), attrs or None])
+        sid = _next_id()
+        tm = self._traceme
+        stack.append([name, sid, parent_id, _pc(), attrs or None,
+                      tm(name, sid=sid) if tm is not None else None])
         return ctx[5]
 
 
+def _traceme_type():
+    """The profiler's scoped host event (`jax.profiler.TraceAnnotation`,
+    a TraceMe), or None where jax is not importable."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
 TRACER = Tracer()
+
+
+def device_get(x, **attrs):
+    """The served path's blocking device read: `jax.device_get(x)`
+    inside a `device.sync` span (`attrs` ride on the span). Every
+    blocking read of the serve path goes through here, so the span
+    covers exactly the wait for the device and the copy back."""
+    import jax
+
+    with TRACER.span("device.sync", **attrs):
+        return jax.device_get(x)

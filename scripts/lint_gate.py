@@ -28,9 +28,7 @@ against a tiny serve workload, proving the fault-injection + recovery
 fabric invariants — docs/ROBUSTNESS.md), the telemetry smoke (a
 traced serve workload whose /metrics scrape must parse and whose
 dispatch-gap report must be non-empty — docs/OBSERVABILITY.md), and
-the sentinel smoke (record a perf baseline, replay it to an `ok`
-verdict, then prove a synthetic 3x phase slowdown exits nonzero —
-docs/OBSERVABILITY.md "Sentinel"), and the lane smoke (the vmapped-lane
+the lane smoke (the vmapped-lane
 vs fused-slot standing-query comparison at S=256 with membership churn:
 >=10x events/s floor, identical event totals, lane dispatches/poll <=4
 — docs/SERVING.md "Standing queries"). Rides the tier-1 pytest run via
@@ -38,8 +36,8 @@ tests/test_lint_gate.py and is runnable standalone:
 
     python scripts/lint_gate.py [--format json|sarif]
         [--no-spmd-smoke] [--no-dataflow-smoke] [--no-warmup-smoke]
-        [--no-chaos-smoke] [--no-telemetry-smoke] [--no-sentinel-smoke]
-        [--no-fleet-smoke] [--no-rehome-smoke] [--no-approx-smoke]
+        [--no-chaos-smoke] [--no-telemetry-smoke] [--no-fleet-smoke]
+        [--no-rehome-smoke] [--no-approx-smoke]
         [--no-wire-smoke] [--no-ring-smoke] [--no-lane-smoke]
 
 Rule catalog + waiver syntax: docs/ANALYSIS.md.
@@ -194,133 +192,6 @@ def telemetry_smoke() -> int:
         file=sys.stderr)
     for f in failures:
         print(f"telemetry smoke: FAIL {f}", file=sys.stderr)
-    return 1 if failures else 0
-
-
-def sentinel_smoke() -> int:
-    """The perf-regression sentinel loop, self-relative (docs/
-    OBSERVABILITY.md "Sentinel"): record a baseline from a tiny traced
-    serve workload, replay the identical workload, and require the
-    comparison to verdict `ok` (no false regression on CI jitter);
-    then inject a synthetic 3x slowdown into one phase's samples and
-    require `regressed` with a nonzero exit code (a real slowdown
-    cannot slip through). Self-relative on purpose — wall-clock
-    baselines do not transfer across CI hosts, so the property CI can
-    assert anywhere is exactly record -> replay -> verdict. Stderr-only
-    like the other smokes."""
-    _pin_cpu()
-    import tempfile
-
-    import numpy as np
-
-    from geomesa_tpu.core.columnar import FeatureBatch
-    from geomesa_tpu.core.sft import SimpleFeatureType
-    from geomesa_tpu.plan.datastore import DataStore
-    from geomesa_tpu.serve.service import QueryService, ServeConfig
-    from geomesa_tpu.telemetry import RECORDER, TRACER, sentinel
-    from geomesa_tpu.telemetry.prof import PROFILER
-
-    failures = []
-    rng = np.random.default_rng(9)
-    n = 256
-    sft = SimpleFeatureType.from_spec(
-        "sentsmoke", "name:String,dtg:Date,*geom:Point")
-
-    def workload(store):
-        # SEQUENTIAL requests on purpose: each one is its own dispatch
-        # window, so every per-phase reservoir collects >= min_n
-        # samples and the comparison verdicts instead of answering
-        # insufficient-data (a single coalesced window would fold one
-        # sample per phase). result_cache=0 + 8 exact counts keep the
-        # plan/residency/filter.mask families sampled past min_n now
-        # that ring-served kNN windows pay them only at arm time
-        # (docs/SERVING.md "Persistent serve loop")
-        svc = QueryService(store, ServeConfig(max_wait_ms=1.0,
-                                              result_cache=0))
-        qp = rng.uniform(-60, 60, (10, 2))
-        cql = "BBOX(geom, -180, -90, 180, 90)"
-        for i in range(10):
-            svc.knn("sentsmoke", cql, qp[i:i + 1, 0],
-                    qp[i:i + 1, 1], k=4).result(timeout=180)
-        for _ in range(8):
-            svc.count("sentsmoke", cql).result(timeout=180)
-        svc.close(drain=True)
-
-    TRACER.enable()
-    try:
-        with tempfile.TemporaryDirectory() as tmp:
-            store = DataStore(tmp, use_device_cache=True)
-            src = store.create_schema(sft)
-            src.write(FeatureBatch.from_pydict(sft, {
-                "name": rng.choice(["a", "b"], n).tolist(),
-                "dtg": rng.integers(
-                    1_590_000_000_000, 1_600_000_000_000, n),
-                "geom": np.stack([rng.uniform(-170, 170, n),
-                                  rng.uniform(-80, 80, n)], 1),
-            }))
-            workload(store)  # warm pass: compiles stay out of both
-            RECORDER.clear()
-            PROFILER.reset()
-            PROFILER.enable()
-            workload(store)
-            base = sentinel.baseline_from_profile(
-                PROFILER.snapshot(include_samples=True))
-            # round-trip through disk exactly like the real workflow
-            # (bench-serve --record-baseline -> gmtpu sentinel)
-            with tempfile.NamedTemporaryFile(
-                    "w", suffix=".json", delete=False) as tf:
-                base_path = tf.name
-            sentinel.save_baseline(base_path, base)
-            base = sentinel.load_baseline(base_path)
-            os.unlink(base_path)
-            PROFILER.reset()
-            workload(store)
-            current = sentinel.baseline_from_profile(
-                PROFILER.snapshot(include_samples=True))
-    finally:
-        PROFILER.disable()
-        TRACER.disable()
-    replay = sentinel.compare(base, current)
-    if replay["regressed"] or sentinel.exit_code(replay) != 0:
-        failures.append(
-            f"identical replay verdicted regressed: "
-            f"{[k for k, v in replay['metrics'].items() if v['verdict'] == 'regressed']}")
-    if sentinel.exit_code(replay, strict=True) != 0:
-        # the identical replay must COMPARE every baseline metric: an
-        # insufficient-data verdict here means a phase/kernel family
-        # stopped being instrumented (or the workload stopped sampling
-        # it), which would silently un-guard that metric in every
-        # future sentinel run
-        failures.append(
-            f"identical replay left metrics uncompared: "
-            f"{[k for k, v in replay['metrics'].items() if v['verdict'] == 'insufficient-data']}")
-    # synthetic regression: one phase 3x slower, everything else as
-    # measured — the sentinel must flag exactly a regression and the
-    # exit code must go nonzero
-    slowed = {k: dict(v) for k, v in current["metrics"].items()}
-    victim = ("phase.dispatch" if "phase.dispatch" in slowed
-              else next(iter(slowed)))
-    slowed[victim] = {
-        "n": current["metrics"][victim]["n"],
-        "median_ms": current["metrics"][victim]["median_ms"] * 3.0,
-        "samples_ms": [v * 3.0 for v in
-                       current["metrics"][victim]["samples_ms"]],
-    }
-    tripped = sentinel.compare(base, {"metrics": slowed})
-    if not tripped["regressed"] or sentinel.exit_code(tripped) == 0:
-        failures.append(
-            f"synthetic 3x slowdown on {victim} not flagged: "
-            f"{tripped['metrics'].get(victim)}")
-    elif tripped["metrics"][victim]["verdict"] != "regressed":
-        failures.append(
-            f"victim verdict {tripped['metrics'][victim]['verdict']}, "
-            f"expected regressed")
-    print(
-        f"sentinel smoke: replay {replay['counts']}, synthetic-3x on "
-        f"{victim} -> {tripped['metrics'].get(victim, {}).get('verdict')}"
-        f" (exit {sentinel.exit_code(tripped)})", file=sys.stderr)
-    for f in failures:
-        print(f"sentinel smoke: FAIL {f}", file=sys.stderr)
     return 1 if failures else 0
 
 
@@ -1155,10 +1026,6 @@ def main(argv=None) -> int:
                    help="skip the telemetry smoke (traced serve "
                         "workload + /metrics parse + gap report; text "
                         "mode only)")
-    p.add_argument("--no-sentinel-smoke", action="store_true",
-                   help="skip the perf-regression sentinel smoke "
-                        "(record -> replay -> ok; synthetic 3x "
-                        "slowdown -> regressed; text mode only)")
     p.add_argument("--no-fleet-smoke", action="store_true",
                    help="skip the replica-fleet smoke (2-replica "
                         "fleet on CPU, one scripted kill, zero "
@@ -1216,8 +1083,6 @@ def main(argv=None) -> int:
         rc = chaos_smoke()
     if args.format == "text" and not args.no_telemetry_smoke and rc == 0:
         rc = telemetry_smoke()
-    if args.format == "text" and not args.no_sentinel_smoke and rc == 0:
-        rc = sentinel_smoke()
     if args.format == "text" and not args.no_fleet_smoke and rc == 0:
         rc = fleet_smoke()
     if args.format == "text" and not args.no_rehome_smoke and rc == 0:

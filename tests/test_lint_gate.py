@@ -13,15 +13,13 @@ GATE = os.path.join(REPO_ROOT, "scripts", "lint_gate.py")
 
 def test_lint_gate_passes_on_shipped_tree():
     # --no-spmd-smoke / --no-dataflow-smoke / --no-chaos-smoke /
-    # --no-telemetry-smoke /
-    # --no-sentinel-smoke / --no-fleet-smoke / --no-wire-smoke /
+    # --no-telemetry-smoke / --no-fleet-smoke / --no-wire-smoke /
     # --no-ring-smoke: those invariants already run in-process in this
     # same tier-1 suite (tests/test_analysis_spmd.py dirty-fixture
     # replays for every SPMD rule; tests/test_analysis_dataflow.py
     # dirty/clean fixtures for every dataflow rule plus the SARIF
     # provenance-chain assertion; tests/test_faults.py chaos
     # regression; tests/test_telemetry.py trace/scrape/gap checks;
-    # tests/test_slo_observability.py sentinel record/replay/verdict;
     # tests/test_fleet.py kill-mid-burst failover + subscription
     # re-home across an owner kill (TestRehome); tests/test_wire.py
     # columnar parity + one-encode fan-out; tests/test_ringloop.py ring
@@ -32,9 +30,8 @@ def test_lint_gate_passes_on_shipped_tree():
     # standalone `python scripts/lint_gate.py` CI runs.
     r = subprocess.run([sys.executable, GATE, "--no-spmd-smoke",
                         "--no-dataflow-smoke", "--no-chaos-smoke",
-                        "--no-telemetry-smoke", "--no-sentinel-smoke",
-                        "--no-fleet-smoke", "--no-rehome-smoke",
-                        "--no-approx-smoke",
+                        "--no-telemetry-smoke", "--no-fleet-smoke",
+                        "--no-rehome-smoke", "--no-approx-smoke",
                         "--no-wire-smoke", "--no-ring-smoke",
                         "--no-lane-smoke"],
                        capture_output=True, text=True, cwd=REPO_ROOT)

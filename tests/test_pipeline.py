@@ -433,3 +433,254 @@ class TestStagerAndDonation:
         np.testing.assert_allclose(np.asarray(out), 3.0)
         with pytest.raises(KeyError):
             reg.serve_variant("t.missing", donate_argnums=(0,))
+
+
+# -- the named stretches of a traced window ---------------------------------
+
+
+def _traced_fake_run(depth=2, windows=3):
+    """Three gated windows at depth 2 with tracing on: window 3 must
+    wait for a slot while windows 1 and 2 are in flight. Returns the
+    live traces of the three leads, in submit order."""
+    import time as _t
+
+    from geomesa_tpu.serve.scheduler import ServeRequest
+    from geomesa_tpu.telemetry import TRACER
+
+    events: list = []
+    gates = [threading.Event() for _ in range(windows)]
+    TRACER.enable()
+    try:
+        svc = fake_service(events, gates, pipeline_depth=depth,
+                           trace_sync=True)
+        reqs = []
+        for i in range(windows):
+            r = ServeRequest(kind="knn", query=Query(
+                "t", f"BBOX(geom, 0, 0, 1, {i + 1})"))
+            r.qx, r.qy, r.k = np.array([0.0]), np.array([0.0]), 5
+            reqs.append((r, svc.submit(r)))
+        svc.start()
+        deadline = _t.monotonic() + 10
+        while ("launch", depth) not in events:
+            assert _t.monotonic() < deadline, events
+            _t.sleep(0.002)
+        _t.sleep(0.02)  # window depth+1 now stands at the slot
+        for g in gates:
+            g.set()
+        for _, f in reqs:
+            f.result(timeout=30)
+        svc.close(drain=True)
+    finally:
+        TRACER.disable()
+    return [r.trace for r, _ in reqs]
+
+
+class TestWindowStretches:
+    def test_slot_wait_positive_behind_depth_windows(self):
+        traces = _traced_fake_run()
+        waits = []
+        for tr in traces:
+            (w,) = [s for s in tr.snapshot_spans()
+                    if s.name == "pipeline.slot_wait"]
+            waits.append(w.duration_ns)
+        # windows 1 and 2 found a free slot: zero-length waits; window
+        # 3 stood behind them until window 1's slot freed
+        assert waits[0] == 0 and waits[1] == 0
+        assert waits[2] >= 10_000_000, waits
+
+    def test_pending_open_and_finish_hang_off_the_window(self):
+        for tr in _traced_fake_run():
+            spans = tr.snapshot_spans()
+            (win,) = [s for s in spans if s.name == "dispatch"]
+            by = {}
+            for s in spans:
+                by.setdefault(s.name, []).append(s)
+            for name in ("window.open", "pipeline.slot_wait",
+                         "pipeline.pending", "prepare", "merge"):
+                (s,) = by[name]
+                assert s.parent_id == win.span_id, name
+                assert win.start_ns <= s.start_ns <= s.end_ns \
+                    <= win.end_ns, name
+            (fin,) = by["window.finish"]
+            # the window span closes first; its finish bookkeeping is a
+            # child of the root carrying the window's id
+            assert fin.parent_id == tr.root.span_id
+            assert fin.attrs == {"window": win.span_id}
+            assert fin.start_ns >= win.end_ns
+
+
+def _banded_store(tmp_path_factory):
+    """A 2^18-row store with rows placed ON the edges of BOX, so the
+    count's f32 band holds rows to refine in f64. Its windows are long
+    enough that the few µs of glue between spans stay a small share on
+    a loaded CPU."""
+    n = 1 << 18
+    sft, batch = make_batch(n=n, seed=11)
+    geom = np.asarray(batch.columns["geom"].x).copy(), \
+        np.asarray(batch.columns["geom"].y).copy()
+    rng = np.random.default_rng(12)
+    edge = rng.choice(n, 64, replace=False)
+    xs, ys = geom
+    xs[edge[:32]] = -120.0 + rng.choice([-1e-9, 0.0, 1e-9], 32)
+    ys[edge[32:]] = 60.0 + rng.choice([-1e-9, 0.0, 1e-9], 32)
+    batch = FeatureBatch.from_pydict(sft, {
+        "name": list(batch.columns["name"].decode()),
+        "score": np.asarray(batch.columns["score"]),
+        "dtg": np.asarray(batch.columns["dtg"]),
+        "geom": np.stack([xs, ys], 1)})
+    ds = DataStore(str(tmp_path_factory.mktemp("banded")),
+                   use_device_cache=True)
+    ds.create_schema(sft).write(batch)
+    return ds
+
+
+BOX = "BBOX(geom, -120, -60, 120, 60) AND score > -5"
+
+
+@pytest.fixture(scope="module")
+def banded(tmp_path_factory):
+    return _banded_store(tmp_path_factory)
+
+
+ROUTES = {
+    "count": (dict(max_batch=1, result_cache=0), "count"),
+    "pipelined_knn": (dict(max_batch=8, max_wait_ms=1.0, ring=False),
+                      "knn"),
+    "ring_knn": (dict(max_batch=8, max_wait_ms=1.0), "knn"),
+}
+
+
+@pytest.mark.parametrize("route", sorted(ROUTES))
+def test_traced_window_is_named_end_to_end(banded, route, monkeypatch):
+    """A traced count with band rows, a pipelined and a ring kNN window:
+    every blocking read lies under `device.sync`, the new stretches
+    hang off the right parents, and under 5% of window time is left
+    unnamed (benchmark/inside.py's arithmetic)."""
+    import jax
+
+    from benchmark import inside
+    from geomesa_tpu.serve.scheduler import ServeRequest
+    from geomesa_tpu.telemetry import TRACER
+
+    cfg, kind = ROUTES[route]
+    real_get = jax.device_get
+    bare_reads = []
+
+    def watched_get(x):
+        ctx = getattr(TRACER._tls, "ctx", None)
+        if ctx is not None and not (ctx[2] and ctx[2][-1][0]
+                                    == "device.sync"):
+            bare_reads.append(ctx[2][-1][0] if ctx[2] else "<none>")
+        return real_get(x)
+
+    def request(i):
+        r = ServeRequest(kind=kind, query=Query("served", BOX))
+        if kind == "knn":
+            r.qx = np.array([-60.0 + 4 * i])
+            r.qy = np.array([-30.0 + 2 * i])
+            r.k = 5
+        return r
+
+    svc = QueryService(banded, ServeConfig(trace=True, **cfg))
+    try:
+        svc.submit(request(0)).result(timeout=300)  # compiles, arms
+        monkeypatch.setattr(jax, "device_get", watched_get)
+        reqs = []
+        for i in range(12):
+            r = request(i + 1)
+            svc.submit(r).result(timeout=120)
+            reqs.append(r)
+    finally:
+        svc.close(drain=True)
+        TRACER.disable()
+    assert not bare_reads, bare_reads
+    docs = [r.trace.to_json() for r in reqs]
+    names = {s["name"] for d in docs for s in d["spans"]}
+    assert "device.sync" in names
+    if kind == "count":
+        assert "band.refine" in names
+        refine = [s for d in docs for s in d["spans"]
+                  if s["name"] == "band.refine"]
+        assert all(s["attrs"]["rows"] > 0 for s in refine)
+    else:
+        assert {"knn.canonical", "pipeline.slot_wait", "pipeline.pending",
+                "window.finish", "window.open", "merge"} <= names
+        for d in docs:
+            by_id = {s["id"]: s for s in d["spans"]}
+            for s in d["spans"]:
+                if s["name"] == "knn.canonical":
+                    # a sibling of the read, not inside it
+                    assert by_id[s["parent"]]["name"] == "dispatch"
+        assert ("ring.slot" in names) == (route == "ring_knn")
+    share = inside.window_unspanned_pct(docs)
+    assert share is not None and share < 5.0, share
+
+
+class TestDispatcherLoad:
+    def test_loop_counters_monotone_and_idle_is_waiting(self):
+        import time as _t
+
+        svc = fake_service([], [], pipeline_depth=2)
+        st0 = svc.stats()
+        assert st0["loop_ns"] == 0 and st0["loop_wait_ns"] == 0
+        svc.start()
+        _t.sleep(0.15)
+        st1 = svc.stats()
+        _t.sleep(0.3)
+        st2 = svc.stats()
+        svc.close(drain=True)
+        st3 = svc.stats()
+        for a, b in ((st0, st1), (st1, st2), (st2, st3)):
+            assert b["loop_ns"] >= a["loop_ns"]
+            assert b["loop_wait_ns"] >= a["loop_wait_ns"]
+        assert st3["loop_wait_ns"] <= st3["loop_ns"]
+        d_loop = st2["loop_ns"] - st1["loop_ns"]
+        d_wait = st2["loop_wait_ns"] - st1["loop_wait_ns"]
+        # an idle service spends its loop parked in queue.pop
+        assert d_loop > 0.2e9 and d_wait / d_loop > 0.95, (d_loop, d_wait)
+
+    def test_loop_counters_stay_cumulative_across_restart(self):
+        import time as _t
+
+        svc = fake_service([], [], pipeline_depth=2)
+        svc.start()
+        _t.sleep(0.1)
+        svc.close(drain=True)
+        first = svc.stats()
+        svc.start()
+        _t.sleep(0.1)
+        svc.close(drain=True)
+        second = svc.stats()
+        assert second["loop_ns"] >= first["loop_ns"] + 0.05e9
+        assert second["loop_wait_ns"] > first["loop_wait_ns"]
+
+    def test_busy_dispatcher_waits_little(self, monkeypatch):
+        """A dispatcher with work queued spends its loop dispatching:
+        the wait share drops well below the idle one."""
+        import time as _t
+
+        import geomesa_tpu.serve.pipeline as pl
+        from benchmark.inside import dispatch_busy_pct
+
+        real = pl.stack_queries
+
+        def slow_stack(reqs):
+            _t.sleep(0.05)  # host work on the dispatch thread
+            return real(reqs)
+
+        monkeypatch.setattr(pl, "stack_queries", slow_stack)
+        gates = [threading.Event() for _ in range(6)]
+        for g in gates:
+            g.set()
+        svc = fake_service([], gates, pipeline_depth=2)
+        futs = [svc.knn("t", f"BBOX(geom, 0, 0, 1, {i + 1})",
+                        np.array([0.0]), np.array([0.0]), k=5)
+                for i in range(6)]
+        st0 = svc.stats()
+        svc.start()
+        for f in futs:
+            f.result(timeout=30)
+        st1 = svc.stats()
+        svc.close(drain=True)
+        busy = dispatch_busy_pct(st0, st1)
+        assert busy is not None and busy > 50.0, (st0, st1)

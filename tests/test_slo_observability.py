@@ -2,8 +2,7 @@
 math on a fake clock, the closed serve loop (injected latency fault ->
 budget burn -> degradation ladder engages -> recovery, observable via
 /debug/slo), the continuous profiler's fold semantics + overhead
-budget, the regression sentinel's typed verdicts, and the
-scrape-vs-fold races (/debug/gap + /metrics + /debug/prof under
+budget, and the scrape-vs-fold races (/debug/gap + /metrics + /debug/prof under
 concurrent mesh+pipelined traffic).
 
 Wall-clock discipline (tier-1 budget is near-full): the serve fixtures
@@ -22,7 +21,6 @@ import urllib.request
 import numpy as np
 import pytest
 
-from geomesa_tpu.telemetry import sentinel
 from geomesa_tpu.telemetry.prof import ContinuousProfiler, render_prof
 from geomesa_tpu.telemetry.slo import (SloEngine, SloSpec,
                                        parse_toml_subset, render_slo)
@@ -400,7 +398,7 @@ class TestServeClosedLoop:
             req.future.set_exception(
                 QueryRejected("shutting_down", "service closed"))
             svc._finish_window([req], [], req, req.enqueued_at,
-                               _time.monotonic(), 0, None, 0, 0, [], [],
+                               _time.monotonic(), 0, None, 0, [], [],
                                pipelined=True)
             obs = list(svc.slo._obs)
             assert obs and obs[-1][2] == "rejected", obs
@@ -613,106 +611,11 @@ class TestProfiler:
         assert per < 1000.0, f"disabled maybe_fold {per:.0f}ns"
 
 
-# -- sentinel ---------------------------------------------------------------
-
-
-def profile_metrics(scale=1.0, n=40, seed=1):
-    rng = np.random.default_rng(seed)
-    p = ContinuousProfiler()
-    p.enable()
-    for i in range(n):
-        p.fold(synth_trace(i, scale=scale * (1 + rng.uniform(0, 0.04))))
-    return sentinel.baseline_from_profile(
-        p.snapshot(include_samples=True))
-
-
-class TestSentinel:
-    def test_identical_replay_is_ok(self):
-        base = profile_metrics(seed=1)
-        cur = profile_metrics(seed=2)
-        rep = sentinel.compare(base, cur)
-        assert not rep["regressed"]
-        assert sentinel.exit_code(rep) == 0
-        assert all(v["verdict"] == "ok"
-                   for v in rep["metrics"].values()), rep["metrics"]
-
-    def test_slowdown_regresses_and_exit_nonzero(self):
-        rep = sentinel.compare(profile_metrics(), profile_metrics(3.0))
-        assert rep["regressed"] and sentinel.exit_code(rep) == 1
-        assert rep["metrics"]["phase.dispatch"]["verdict"] == "regressed"
-        # queue.wait is unscaled in the synth trace: still ok
-        assert rep["metrics"]["phase.queue.wait"]["verdict"] == "ok"
-        assert "regressed" in sentinel.render_verdicts(rep)
-
-    def test_speedup_reports_improved(self):
-        rep = sentinel.compare(profile_metrics(3.0), profile_metrics())
-        assert not rep["regressed"]
-        assert rep["metrics"]["phase.dispatch"]["verdict"] == "improved"
-
-    def test_insufficient_data_never_verdicts(self):
-        base = profile_metrics(n=3)
-        cur = profile_metrics(n=3, seed=5)
-        rep = sentinel.compare(base, cur)
-        assert all(v["verdict"] == "insufficient-data"
-                   for v in rep["metrics"].values())
-        assert not rep["regressed"]
-        # a metric missing from one side is insufficient, not a crash
-        rep = sentinel.compare(profile_metrics(),
-                               {"metrics": {"only.here": {
-                                   "n": 99, "median_ms": 1.0,
-                                   "samples_ms": [1.0] * 99}}})
-        assert rep["metrics"]["only.here"]["verdict"] == \
-            "insufficient-data"
-        # lost instrumentation must not read as green under --strict:
-        # the default exit stays regression-driven, strict fails on
-        # any uncompared metric
-        assert sentinel.exit_code(rep) == 0
-        assert sentinel.exit_code(rep, strict=True) == 1
-
-    def test_noise_within_overlap_is_not_regression(self):
-        """A modest median shift with overlapping distributions stays
-        ok — the noise-tolerance property that keeps CI quiet."""
-        rng = np.random.default_rng(0)
-        base = {"metrics": {"m": {
-            "n": 64, "median_ms": 1.0,
-            "samples_ms": sorted(rng.normal(1.0, 0.4, 64).clip(0.01))}}}
-        cur = {"metrics": {"m": {
-            "n": 64, "median_ms": 1.6,
-            "samples_ms": sorted(rng.normal(1.6, 0.4, 64).clip(0.01))}}}
-        rep = sentinel.compare(base, cur)
-        assert rep["metrics"]["m"]["verdict"] == "ok"
-
-    def test_baseline_round_trip_and_validation(self, tmp_path):
-        base = profile_metrics()
-        base["context"] = {"mode": "test"}
-        path = str(tmp_path / "BASELINE_SERVE.json")
-        sentinel.save_baseline(path, base)
-        loaded = sentinel.load_baseline(path)
-        assert loaded["metrics"].keys() == base["metrics"].keys()
-        (tmp_path / "bad.json").write_text("{}")
-        with pytest.raises(ValueError, match="not a v1"):
-            sentinel.load_baseline(str(tmp_path / "bad.json"))
-
-    def test_latency_samples_ride_loadgen_reports(self):
-        from geomesa_tpu.serve.loadgen import _report
-
-        rep = _report("closed", 1.0, [0.001 * i for i in range(1, 40)],
-                      39, 0, 0, 0, {})
-        assert rep.samples_ms and rep.samples_ms == sorted(
-            rep.samples_ms)
-        doc = sentinel.baseline_from_profile(
-            {"phases": {}}, latency_samples_ms=rep.samples_ms)
-        assert doc["metrics"]["serve.latency"]["n"] == len(
-            rep.samples_ms)
-        # the JSON report line stays sample-free
-        assert "samples_ms" not in rep.to_json()
-
-
 class TestCliVerbs:
     def test_prof_and_sentinel_from_files(self, tmp_path, capsys):
         import argparse
 
-        from geomesa_tpu.cli.commands import _prof, _sentinel
+        from geomesa_tpu.cli.commands import _prof
 
         p = ContinuousProfiler()
         p.enable()
@@ -725,26 +628,6 @@ class TestCliVerbs:
                                       host="", port=0, json=False))
         assert rc == 0
         assert "continuous profile" in capsys.readouterr().out
-
-        base_path = tmp_path / "base.json"
-        sentinel.save_baseline(
-            str(base_path), sentinel.baseline_from_profile(prof_doc))
-        ns = argparse.Namespace(
-            baseline=str(base_path), input=str(prof_path), url=None,
-            host="", port=0, threshold=None, min_overlap=None,
-            min_n=None, json=True)
-        assert _sentinel(ns) == 0  # identical profile: no regression
-        out = json.loads(capsys.readouterr().out)
-        assert not out["regressed"]
-        p3 = ContinuousProfiler()
-        p3.enable()
-        for i in range(20):
-            p3.fold(synth_trace(i, scale=3.0))
-        slow_path = tmp_path / "slow.json"
-        slow_path.write_text(
-            json.dumps(p3.snapshot(include_samples=True)))
-        ns.input = str(slow_path)
-        assert _sentinel(ns) == 1  # 3x slowdown: nonzero exit
 
 
 # -- gmtpu top --------------------------------------------------------------

@@ -604,3 +604,163 @@ class TestGapReport:
         # same process, same ids = one shared (adopted) window
         rep = gap_report([trace_from("aa", 1), trace_from("aa", 2)])
         assert rep["dispatch_gap"]["windows"] == 1
+
+
+# -- one clock with the profiler --------------------------------------------
+
+
+def _profile(tmp_path, body):
+    """Run `body()` under a CPU jax.profiler trace; the host-plane
+    events as [(name, start_ns, stats dict)]."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.enable_hlo_proto = False
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    return [(e.name, int(e.start_ns), dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for ln in plane.lines for e in ln.events]
+
+
+class TestProfilerMirror:
+    def test_live_spans_land_on_the_host_plane(self, tmp_path):
+        """While tracing is on, every live span is a profiler TraceMe of
+        the same name carrying its span id; the per-span offsets
+        between the two clocks agree to well under 50 µs."""
+        tr = Tracer()
+        tr.enable()
+        trace = tr.start_trace("q")
+
+        def body():
+            with tr.scope(trace):
+                for _ in range(200):
+                    with tr.span("mirror.outer"):
+                        with tr.span("mirror.inner", k=1):
+                            pass
+
+        events = _profile(tmp_path, body)
+        spans = {s.span_id: s for s in trace.snapshot_spans()}
+        got = [(n, t0, st) for n, t0, st in events
+               if n in ("mirror.outer", "mirror.inner")]
+        assert len(got) == len(spans) == 400
+        offsets = []
+        for name, t0, stats in got:
+            s = spans[int(stats["sid"])]
+            assert s.name == name
+            offsets.append(t0 - s.start_ns)
+        assert max(offsets) - min(offsets) < 50_000, (
+            min(offsets), max(offsets))
+
+    def test_recorded_spans_and_no_profiler_issue_no_traceme(
+            self, tmp_path, monkeypatch):
+        """Spans stamped after the fact have no TraceMe, and where the
+        profiler's type cannot be had (no jax) live spans still record."""
+        from geomesa_tpu.telemetry import trace as T
+
+        monkeypatch.setattr(T, "_traceme_type", lambda: None)
+        tr = Tracer()
+        tr.enable()
+        trace = tr.start_trace("q")
+
+        def body():
+            with tr.scope(trace):
+                with tr.span("mirror.off"):
+                    pass
+            t0 = time.perf_counter_ns()
+            trace.record("mirror.recorded", t0, t0 + 10)
+
+        names = {n for n, _, _ in _profile(tmp_path, body)}
+        assert not names & {"mirror.off", "mirror.recorded"}
+        assert {s.name for s in trace.snapshot_spans()} == {
+            "mirror.off", "mirror.recorded"}
+
+    def test_disabled_span_stays_the_shared_noop(self):
+        tr = Tracer()
+        tr.enable()
+        tr.disable()
+        trace = Trace("q")
+        with tr.scope(trace):
+            assert tr.span("x") is NOOP_SPAN
+            assert tr.span("y", a=1) is NOOP_SPAN
+        assert trace.span_count() == 0
+
+
+class TestDeviceGet:
+    def test_read_is_one_device_sync_span(self):
+        import jax.numpy as jnp
+
+        from geomesa_tpu.telemetry.trace import TRACER, device_get
+
+        x = jnp.arange(8) * 2
+        assert device_get(x).tolist() == list(range(0, 16, 2))  # unscoped
+        TRACER.enable()
+        try:
+            trace = TRACER.start_trace("q")
+            with TRACER.scope(trace):
+                with TRACER.span("outer"):
+                    got = device_get((x, x + 1), ring=True)
+        finally:
+            TRACER.disable()
+        assert int(got[1][0]) == 1
+        spans = {s.name: s for s in trace.snapshot_spans()}
+        sync = spans["device.sync"]
+        assert sync.parent_id == spans["outer"].span_id
+        assert sync.attrs == {"ring": True}
+
+
+class TestStallStamps:
+    def test_since_carries_each_stall_start(self):
+        from geomesa_tpu.compilecache.stall import StallMeter
+
+        m = StallMeter()
+        tok = m.token()
+        t0 = time.perf_counter_ns()
+        m.note("k:a", 0.002, start_ns=t0 - 5_000_000)
+        m.note("k:b", 0.001)
+        t1 = time.perf_counter_ns()
+        (la, sa, da), (lb, sb, db) = m.since(tok)
+        assert (la, sa, da) == ("k:a", t0 - 5_000_000, 0.002)
+        # no stamp given: the stall ended as it was noted
+        assert lb == "k:b" and db == 0.001
+        assert t0 - 1_000_000 <= sb <= t1 - 1_000_000
+        assert m.since(m.token()) == []
+
+    def test_compile_stall_span_at_its_real_interval(self):
+        from types import SimpleNamespace
+
+        from geomesa_tpu.plan.query import Query
+        from geomesa_tpu.serve.scheduler import ServeRequest
+        from geomesa_tpu.serve.service import QueryService, ServeConfig
+        from geomesa_tpu.telemetry.trace import TRACER
+
+        store = SimpleNamespace(get_feature_source=None, audit=None)
+        svc = QueryService(store, ServeConfig(), autostart=False)
+        TRACER.enable()
+        try:
+            req = ServeRequest(kind="count", query=Query("t", "INCLUDE"))
+            req.trace = TRACER.start_trace("query", kind="count")
+        finally:
+            TRACER.disable()
+        req.future.set_running_or_notify_cancel()
+        req.future.set_result(1)
+        w0 = time.perf_counter_ns()
+        wid = 12345
+        stall0 = w0 + 1_000_000
+        svc._finish_window([req], [], req, time.monotonic(),
+                           time.monotonic(), 0, wid, w0 + 9_000_000,
+                           [("filter:mask:x", stall0, 0.003)], [])
+        (st,) = [s for s in req.trace.snapshot_spans()
+                 if s.name == "compile.stall"]
+        assert (st.start_ns, st.end_ns) == (stall0, stall0 + 3_000_000)
+        assert st.parent_id == wid
+        assert st.attrs == {"label": "filter:mask:x"}
