@@ -576,10 +576,36 @@ class QueryPlanner:
                     )
                 )
 
+    def _lanes(self, sb, partitions):
+        """Partition pruning over the superbatch: (allowed, lanes), with
+        `allowed` the host bool [P] of the plan's resident partition ids
+        and `lanes` the device bool [N] `allowed[sb.pids]`, computed by
+        ONE `lane_mask` launch (a range compare against the runs of
+        allowed ids, no gather). (None, None) when none is resident.
+        Each launch adds its run count to `query.lane.runs`: a selection
+        that fragments (a date + Z2 scheme, residency holes) shows there."""
+        from geomesa_tpu.store.cache import lane_mask, lane_runs
+        from geomesa_tpu.utils.metrics import metrics
+
+        with TRACER.span("kernel.dispatch", kernel="lane.mask") as span:
+            allowed = np.zeros(max(len(sb.ids), 1), bool)
+            for name in partitions:
+                i = sb.ids.get(name)
+                if i is not None:
+                    allowed[i] = True
+            runs, r = lane_runs(allowed)
+            span.set(runs=r)
+            if not r:
+                return None, None
+            metrics.counter("query.lane.masks")
+            metrics.counter("query.lane.runs", r)
+            return allowed, lane_mask(sb.pids, runs)
+
     def _execute_cached(self, plan: QueryPlan, query: Query):
         """HBM-resident execution over the cache's SUPERBATCH: one dense
         kernel over every resident row, with partition pruning applied as a
-        lane mask (allowed[pid]) instead of per-partition dispatches.
+        lane mask (`_lanes`, computed once per request) instead of
+        per-partition dispatches.
         Returns (result, mask_count, t_scan); "scan time" here is the
         cache-ensure (load of any non-resident partition).
 
@@ -599,12 +625,8 @@ class QueryPlanner:
         sb = self.cache.superbatch()
         if sb is None:
             return self._empty_result(hints, query), 0, t_scan
-        allowed = np.zeros(max(len(sb.ids), 1), bool)
-        for name in plan.partitions:
-            i = sb.ids.get(name)
-            if i is not None:
-                allowed[i] = True
-        if not allowed.any():
+        _, lanes = self._lanes(sb, plan.partitions)
+        if lanes is None:
             return self._empty_result(hints, query), 0, t_scan
 
         with TRACER.span("kernel.dispatch", kernel="filter.mask"):
@@ -613,7 +635,7 @@ class QueryPlanner:
                 if plan.compiled is not None
                 else sb.dev["__valid__"]
             )
-            dev_mask = dev_mask & jnp.asarray(allowed)[sb.pids]
+            dev_mask = dev_mask & lanes
         has_band = plan.compiled is not None and plan.compiled.has_band
         from geomesa_tpu.plan.runner import visibility_mask
 
@@ -626,10 +648,7 @@ class QueryPlanner:
                 total = jnp.sum(dev_mask, dtype=jnp.int64)
             total = int(device_get(total))
             if has_band:
-                with TRACER.span("kernel.dispatch", kernel="lane.gather"):
-                    extra = jnp.asarray(allowed)[sb.pids]
-                    if vm is not None:
-                        extra = extra & jnp.asarray(vm)
+                extra = lanes if vm is None else lanes & jnp.asarray(vm)
                 total += plan.compiled.band_count_correction(
                     sb.dev, sb.batch, dev_mask, extra=extra)
             return QueryResult("count", count=total), total, t_scan
@@ -664,7 +683,7 @@ class QueryPlanner:
             # non-inplace: refine returns the caller's (possibly read-
             # only numpy-view) mask unchanged when no rows are flagged
             mask = plan.compiled.refine(mask, sb.dev, sb.batch)
-            mask = mask & allowed[np.asarray(sb.pids)]
+            mask = mask & device_get(lanes)
             if vm is not None:
                 mask = mask & vm
         total = int(mask.sum())
@@ -697,12 +716,8 @@ class QueryPlanner:
                 sb = self.cache.superbatch()
             if sb is None:
                 return None, None, None, None, True
-            allowed = np.zeros(max(len(sb.ids), 1), bool)
-            for name in plan.partitions:
-                i = sb.ids.get(name)
-                if i is not None:
-                    allowed[i] = True
-            if not allowed.any():
+            allowed, lanes = self._lanes(sb, plan.partitions)
+            if lanes is None:
                 return None, None, None, None, True
             batch, dev = sb.batch, sb.dev
             with TRACER.span("kernel.dispatch", kernel="filter.mask"):
@@ -711,7 +726,7 @@ class QueryPlanner:
                     if plan.compiled is not None
                     else dev["__valid__"]
                 )
-                mask = mask & jnp.asarray(allowed)[sb.pids]
+                mask = mask & lanes
             note_device_op()
             if plan.compiled is not None and plan.compiled.has_band:
                 # f64 band refinement, device-resident: exact values

@@ -27,6 +27,8 @@ import os
 import threading
 from typing import Dict, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from geomesa_tpu.core.columnar import FeatureBatch
@@ -62,10 +64,39 @@ class CacheEntry:
     dev: Optional[dict] = None  # per-partition device segment (flat stores)
 
 
+def lane_runs(allowed) -> Tuple[np.ndarray, int]:
+    """The maximal runs of True in the host bool vector `allowed` as
+    inclusive partition-id ranges: (int32 [R', 2] of (first, last),
+    R). R' is R rounded up to a power of two and the padding rows are
+    empty ranges (first > last), so `lane_mask` compiles at most
+    ceil(log2 P) + 1 programs per row count over P partitions."""
+    a = np.asarray(allowed, bool)
+    edges = np.diff(np.concatenate(([False], a, [False])).astype(np.int8))
+    first = np.flatnonzero(edges == 1)
+    last = np.flatnonzero(edges == -1) - 1
+    r = len(first)
+    runs = np.zeros((_next_pow2(max(r, 1)), 2), np.int32)
+    runs[:, 0] = 1  # empty: 1 > 0
+    runs[:r, 0] = first
+    runs[:r, 1] = last
+    return runs, r
+
+
+@jax.jit
+def lane_mask(pids, runs):
+    """`allowed[pids]` without a gather: a row is in a lane the plan
+    keeps iff its partition id falls in one of `runs` (`lane_runs`).
+    Elementwise over `pids` (the [R', N] compare fuses into the reduce),
+    so the result takes `pids`' sharding."""
+    p = pids[None, :]
+    return jnp.any((p >= runs[:, :1]) & (p <= runs[:, 1:]), axis=0)
+
+
 @dataclasses.dataclass
 class SuperBatch:
     """All resident partitions as ONE device batch + a partition-id row
-    column. Execution masks pruned-out partitions by lane (allowed[pid])
+    column. Execution masks pruned-out partitions by lane — a range
+    compare of `pids` against the runs of allowed ids (`lane_mask`) —
     instead of dispatching per-partition kernels: with a device round
     trip and a kernel launch per partition, one dense pass over every
     resident row beats dozens of tiny dispatches —
@@ -374,9 +405,6 @@ class DeviceCacheManager:
             return self._super
         if not self._entries:
             return None
-        import jax.numpy as jnp
-        import numpy as np
-
         from geomesa_tpu.engine.device import to_device
 
         names = sorted(self._entries)
@@ -441,8 +469,6 @@ class DeviceCacheManager:
         re-placed from device buffers are bit-identical to a fresh
         upload: the host copies are unchanged and the dict vocab is
         grow-only, so previously-uploaded codes never re-encode."""
-        import jax
-        import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from geomesa_tpu.parallel.mesh import SHARD_AXIS

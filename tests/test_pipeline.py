@@ -510,11 +510,12 @@ class TestWindowStretches:
 
 
 def _banded_store(tmp_path_factory):
-    """A 2^18-row store with rows placed ON the edges of BOX, so the
+    """A 2^21-row store with rows placed ON the edges of BOX, so the
     count's f32 band holds rows to refine in f64. Its windows are long
-    enough that the few µs of glue between spans stay a small share on
-    a loaded CPU."""
-    n = 1 << 18
+    enough that the ~0.25 ms of glue between spans per window stays a
+    small share on a loaded CPU (a count window's device work, a few
+    compares and a sum per row, takes ~4 ms at 2^18 rows)."""
+    n = 1 << 21
     sft, batch = make_batch(n=n, seed=11)
     geom = np.asarray(batch.columns["geom"].x).copy(), \
         np.asarray(batch.columns["geom"].y).copy()
