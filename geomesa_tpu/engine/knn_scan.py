@@ -269,10 +269,11 @@ def _refine(qx, qy, xf, yf, maskf, orig_blk, n, k, blk, blk_ok=None):
     nb = xf.shape[0] // blk
     xb = xf.reshape(nb, blk)
     yb = yf.reshape(nb, blk)
-    vb = maskf.reshape(nb, blk) > 0.5
+    mbk = maskf.reshape(nb, blk)
     gx = jnp.take(xb, orig_blk, axis=0).reshape(q, mb * blk)
     gy = jnp.take(yb, orig_blk, axis=0).reshape(q, mb * blk)
-    gv = jnp.take(vb, orig_blk, axis=0).reshape(q, mb * blk)
+    # compare AFTER the gather: only the [Q, mb*blk] pool, never N rows
+    gv = jnp.take(mbk, orig_blk, axis=0).reshape(q, mb * blk) > 0.5
     if blk_ok is not None:
         gv = gv & jnp.repeat(blk_ok, blk, axis=1)
     lane = (orig_blk[:, :, None] * blk + jnp.arange(blk, dtype=jnp.int32)
@@ -308,7 +309,9 @@ def knn_fullscan(
     compaction, no capacity, no host round trip). Same contract as `knn`:
     returns (dists [Q, k] meters, indices [Q, k] into the original
     arrays). m_blocks >= k required (see module docstring); N is padded
-    to data_tile internally (padded lanes masked out)."""
+    to data_tile internally (padded lanes masked out). `mask` may come
+    already padded (`knn_tile_summary`'s f32 mask, the ring program's
+    overflow fallback)."""
     n = x.shape[0]
     q = qx.shape[0]
     if k > m_blocks:  # trace-time contract: exactness needs m >= k
@@ -317,10 +320,10 @@ def knn_fullscan(
             "selection only guarantees the top-m_blocks elements"
         )
     pad = (-n) % data_tile
+    npad = n + pad
     xf = jnp.pad(x.astype(jnp.float32), (0, pad))
     yf = jnp.pad(y.astype(jnp.float32), (0, pad))
-    maskf = jnp.pad(mask.astype(jnp.float32), (0, pad))
-    npad = n + pad
+    maskf = jnp.pad(mask.astype(jnp.float32), (0, npad - mask.shape[0]))
 
     minima, _ = chord_blockmin(
         qx, qy, xf, yf, maskf,
@@ -362,19 +365,34 @@ def knn_sparse_scan(
     it pow2 from the planner's selectivity estimate — overshoot is cheap,
     dead programs skip the MXU); if more tiles match, `overflow` is True,
     the top-k silently ignored the highest-id matching tiles, and the
-    caller MUST fall back (knn_fullscan). m_blocks >= k required."""
-    n = x.shape[0]
-    if k > m_blocks:
-        raise ValueError(
-            f"k={k} exceeds m_blocks={m_blocks}: the deferred block "
-            "selection only guarantees the top-m_blocks elements"
-        )
-    pad = (-n) % data_tile
-    xf = jnp.pad(x.astype(jnp.float32), (0, pad))
-    yf = jnp.pad(y.astype(jnp.float32), (0, pad))
-    maskf = jnp.pad(mask.astype(jnp.float32), (0, pad))
-    npad = n + pad
-    ntiles = npad // data_tile
+    caller MUST fall back (knn_fullscan). m_blocks >= k required.
+
+    One program made of its two halves: `knn_tile_summary` (the pass
+    over the mask) and the scan over the tiles it lists. The ring
+    program runs the same halves apart (`knn_ring_scan`)."""
+    maskf, tile_ids, n_sel, overflow = knn_tile_summary(
+        mask, tile_capacity=tile_capacity, data_tile=data_tile)
+    fd, fi = _sparse_scan_body(
+        qx, qy, x, y, maskf, tile_ids, n_sel, k=k, m_blocks=m_blocks,
+        blk=blk, data_tile=data_tile, interpret=interpret)
+    return fd, fi, overflow
+
+
+@functools.partial(jax.jit, static_argnames=("tile_capacity", "data_tile"))
+def knn_tile_summary(
+    mask: jax.Array,
+    tile_capacity: int,
+    data_tile: int = DATA_TILE,
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The sparse scan's mask-derived prologue, the one pass over all N
+    rows: (maskf [N padded to data_tile] f32 0/1, tile_ids [C] ascending
+    ids of the match-bearing data tiles padded with 0, n_sel = how many
+    tiles match, overflow = n_sel > C), C = min(tile_capacity, tiles).
+    A function of the mask alone, so a caller whose mask is frozen (the
+    ring program, planner.ring_arm) computes it once."""
+    n = mask.shape[0]
+    maskf = _pad_f32(mask, (-n) % data_tile)
+    ntiles = maskf.shape[0] // data_tile
     tile_capacity = min(tile_capacity, ntiles)
 
     # matching tiles (ascending ids), static capacity
@@ -387,6 +405,34 @@ def knn_sparse_scan(
         tile_capacity,
     )[0]
     tile_ids = jnp.where(picked > -(1 << 30), -picked, 0)
+    return maskf, tile_ids, n_sel, overflow
+
+
+def _pad_f32(v: jax.Array, pad: int) -> jax.Array:
+    v = v.astype(jnp.float32)
+    return jnp.pad(v, (0, pad)) if pad else v
+
+
+def _sparse_scan_body(qx, qy, x, y, maskf, tile_ids, n_sel, k, m_blocks,
+                      blk=BLK, data_tile=DATA_TILE, interpret=False):
+    """The scan half of `knn_sparse_scan` over a `knn_tile_summary`:
+    the Pallas call reads only the listed tiles and `_refine` gathers
+    only the selected blocks, so no op here passes over all N rows
+    (x and y are padded only when N is not a multiple of data_tile).
+    Returns (dists [Q, k], indices [Q, k])."""
+    n = x.shape[0]
+    if k > m_blocks:
+        raise ValueError(
+            f"k={k} exceeds m_blocks={m_blocks}: the deferred block "
+            "selection only guarantees the top-m_blocks elements"
+        )
+    pad = (-n) % data_tile
+    if maskf.shape[0] != n + pad:
+        raise ValueError(
+            f"maskf has {maskf.shape[0]} rows; the tile summary of "
+            f"{n} rows at data_tile={data_tile} has {n + pad}")
+    xf = _pad_f32(x, pad)
+    yf = _pad_f32(y, pad)
 
     minima, _ = chord_blockmin_sparse(
         qx, qy, xf, yf, maskf, tile_ids, n_sel,
@@ -401,9 +447,8 @@ def knn_sparse_scan(
     # matched blocks carry keys <= 12; all-masked and dead blocks >= 1e9)
     blk_ok = vals < jnp.float32(PENALTY / 2)
     orig_blk = jnp.take(tile_ids, selblk // bpt) * bpt + selblk % bpt
-    fd, fi = _refine(qx, qy, xf, yf, maskf, orig_blk, n, k, blk,
-                     blk_ok=blk_ok)
-    return fd, fi, overflow
+    return _refine(qx, qy, xf, yf, maskf, orig_blk, n, k, blk,
+                   blk_ok=blk_ok)
 
 
 # f32 scan-ranking error budget (round 5, VERDICT r4 task 10): the fused
@@ -487,21 +532,25 @@ def knn_exact_refine(qx_np, qy_np, x_np, y_np, fd, fi, k):
 # fixed ring of staging buffers. These raw (un-jitted) callables are the
 # forms the ExecutableRegistry's ring tier compiles for it: argnums 0/1
 # (the slot's qx/qy) are the ONLY per-window inputs — the feature-set
-# arguments (x, y, mask) are pre-bound device references the ring
-# program re-passes unchanged every window, so XLA sees a stable
-# parameter layout and (with donation, non-CPU) reuses the slot HBM
-# across windows. The math is knn_sparse_scan / knn_fullscan_tiled
-# exactly — a distinct callable only so the ring registration can carry
-# its own donation contract without re-keying the base kernels.
+# arguments (x, y, the mask or its tile summary) are pre-bound device
+# references the ring program re-passes unchanged every window, so XLA
+# sees a stable parameter layout and (with donation, non-CPU) reuses
+# the slot HBM across windows. The math is knn_sparse_scan /
+# knn_fullscan_tiled exactly; the sparse form takes the arm-time
+# `knn_tile_summary` of the frozen mask in place of the mask, so a
+# window runs the scan half alone.
 
 
-def knn_ring_scan(qx, qy, x, y, mask, k, tile_capacity, m_blocks,
-                  interpret):
-    """Slot-parameterized sparse scan for the ring tier (see above).
-    Same contract as `knn_sparse_scan`: (dists, idx, overflow)."""
-    return knn_sparse_scan(
-        qx, qy, x, y, mask, k=k, tile_capacity=tile_capacity,
-        m_blocks=m_blocks, interpret=interpret)
+def knn_ring_scan(qx, qy, x, y, maskf, tile_ids, n_sel, overflow, k,
+                  m_blocks, interpret):
+    """Slot-parameterized sparse scan for the ring tier (see above)
+    over an arm-time `knn_tile_summary` (maskf, tile_ids, n_sel,
+    overflow). Same contract as `knn_sparse_scan`: (dists, idx,
+    overflow)."""
+    fd, fi = _sparse_scan_body(
+        qx, qy, x, y, maskf, tile_ids, n_sel, k=k, m_blocks=m_blocks,
+        interpret=interpret)
+    return fd, fi, overflow
 
 
 def knn_ring_fullscan(qx, qy, x, y, mask, k, m_blocks, interpret):
@@ -558,6 +607,8 @@ def knn_sparse_launch(
     JAX's async dispatch means the kernel executes while the caller's
     host thread moves on — the serve pipeline launches window N+1's
     transfer behind this. `knn_sparse_finish` completes the contract."""
+    from geomesa_tpu.utils.metrics import metrics
+
     if tile_capacity is None:
         tile_capacity = capacity_bucket(int(np.asarray(
             count_match_tiles(mask))))
@@ -565,6 +616,7 @@ def knn_sparse_launch(
         qx, qy, x, y, mask, k=k, tile_capacity=tile_capacity,
         m_blocks=m_blocks, interpret=interpret,
     )
+    metrics.counter("knn.tiles.inline")
     return fd, fi, ov, tile_capacity
 
 

@@ -901,7 +901,7 @@ class QueryPlanner:
         from geomesa_tpu.engine.knn_scan import (
             capacity_bucket, count_match_tiles, default_interpret,
             knn_fullscan_tiled, knn_sparse_launch)
-        from geomesa_tpu.utils.metrics import note_device_op
+        from geomesa_tpu.utils.metrics import metrics, note_device_op
 
         if isinstance(query, str):
             query = Query(self.storage.sft.name, query)
@@ -1010,6 +1010,7 @@ class QueryPlanner:
                     # values; knn_fullscan converts on entry)
                     fb_qx = np.asarray(qx, np.float32)
                     fb_qy = np.asarray(qy, np.float32)
+                    metrics.counter("knn.tiles.inline")
                 else:
                     fd, fi, ov, seed_cap = knn_sparse_launch(
                         jqx, jqy, x, y, mask, k=kk,
@@ -1162,6 +1163,7 @@ class QueryPlanner:
         fd, fi, ov = out[0], out[1], out[2]
         count_dev = out[3] if want_mask_count else None
         metrics.counter("knn.mesh.dispatches")
+        metrics.counter("knn.tiles.inline")
         from geomesa_tpu.utils.metrics import note_device_op
 
         note_device_op()
@@ -1371,7 +1373,7 @@ class QueryPlanner:
             prog.mesh = mesh
         else:
             from geomesa_tpu.engine.knn_scan import (
-                knn_ring_fullscan, knn_ring_scan)
+                knn_ring_fullscan, knn_ring_scan, knn_tile_summary)
 
             prog.x, prog.y, prog.mask = x, y, mask
             donate_argnums = (0, 1) if donate else ()
@@ -1385,14 +1387,18 @@ class QueryPlanner:
                     cap = capacity_bucket(int(device_get(
                         count_match_tiles(mask))))
                 prog.cap = cap
+                # the tile list is a function of the frozen mask: build
+                # it once here, not in every window. The f32 mask takes
+                # the bool mask's place (the overflow fallback reads it)
+                (prog.mask, prog.tile_ids, prog.n_sel,
+                 prog.overflow) = knn_tile_summary(mask, tile_capacity=cap)
                 vname = registry.ring_variant(
                     "knn_scan.knn_ring_scan", depth, fn=knn_ring_scan,
                     donate_argnums=donate_argnums,
-                    static_argnames=("k", "tile_capacity", "m_blocks",
-                                     "interpret"))
+                    static_argnames=("k", "m_blocks", "interpret"))
                 prog.handle = registry.compile(
-                    vname, qabs, qabs, x, y, mask, k=kk,
-                    tile_capacity=cap, m_blocks=mb, interpret=interp)
+                    vname, qabs, qabs, x, y, *prog.tiles(), k=kk,
+                    m_blocks=mb, interpret=interp)
             else:
                 prog.route = "fullscan"
                 vname = registry.ring_variant(
@@ -1909,15 +1915,17 @@ class RingProgram:
 
     Everything a window would otherwise recompute per dispatch is
     frozen here: the plan's partitions, the resident superbatch, the
-    f64-exact filter mask (band corrections + visibility folded), the
-    calibrated sparse capacity, the fused-count scalar, and the AOT
-    executable under the registry ring tier. `launch()` is the whole
-    per-window device interaction: ONE executable invocation over the
-    pre-bound feature buffers plus the staged slot pair. `fresh()` is
-    the per-window staleness gate — a lock-peek plus an int compare,
-    never residency work — and a False answer sends the window back to
-    the pipelined route, whose plan/ensure pass rebuilds residency and
-    lets the ring loop re-arm against the new version.
+    f64-exact filter mask (band corrections + visibility folded; on the
+    sparse route its `knn_tile_summary`: the f32 mask, the tile list,
+    its count and the overflow flag), the calibrated sparse capacity,
+    the fused-count scalar, and the AOT executable under the registry
+    ring tier. `launch()` is the whole per-window device interaction:
+    ONE executable invocation over the pre-bound feature buffers plus
+    the staged slot pair. `fresh()` is the per-window staleness gate —
+    a lock-peek plus an int compare, never residency work — and a False
+    answer sends the window back to the pipelined route, whose
+    plan/ensure pass rebuilds residency and lets the ring loop re-arm
+    against the new version.
 
     Bit-identity holds by construction: the kernel, mask, capacity and
     merge are exactly the serial route's, the staged slot carries the
@@ -1926,9 +1934,9 @@ class RingProgram:
 
     __slots__ = ("planner", "plan", "sb", "batch", "k", "kk", "impl",
                  "mb", "interp", "depth", "mversion", "mask_count",
-                 "route", "handle", "x", "y", "mask", "cap", "caps_key",
-                 "placement", "mesh", "mesh_shape", "shards",
-                 "dense_fn")
+                 "route", "handle", "x", "y", "mask", "tile_ids", "n_sel",
+                 "overflow", "cap", "caps_key", "placement", "mesh",
+                 "mesh_shape", "shards", "dense_fn")
 
     def __init__(self, planner, plan, sb, batch, k, kk, impl, mb,
                  interp, depth, mversion):
@@ -1947,6 +1955,7 @@ class RingProgram:
         self.route = "sparse"
         self.handle = None
         self.x = self.y = self.mask = None
+        self.tile_ids = self.n_sel = self.overflow = None
         self.cap = None
         self.caps_key = None
         self.placement = None        # staging placement (mesh: replicated)
@@ -1954,6 +1963,11 @@ class RingProgram:
         self.mesh_shape: tuple = ()
         self.shards: tuple = ()
         self.dense_fn = None         # mesh overflow program builder
+
+    def tiles(self) -> tuple:
+        """The sparse route's frozen scan inputs after x and y:
+        (maskf, tile_ids, n_sel, overflow)."""
+        return self.mask, self.tile_ids, self.n_sel, self.overflow
 
     def fresh(self) -> bool:
         """Cheap per-window staleness gate: the superbatch reference
@@ -2004,13 +2018,15 @@ class RingProgram:
                 launch.arm_mesh(fd, fi, ov, self._dense_fallback(jqx, jqy),
                                 cap=self.cap, caps_key=self.caps_key)
                 metrics.counter("knn.mesh.dispatches")
+                metrics.counter("knn.tiles.inline")
             elif self.route == "fullscan":
                 fd, fi = self.handle.call(
                     jqx, jqy, self.x, self.y, self.mask)
                 launch.arm_dense(fd, fi)
             else:
                 fd, fi, ov = self.handle.call(
-                    jqx, jqy, self.x, self.y, self.mask)
+                    jqx, jqy, self.x, self.y, *self.tiles())
+                metrics.counter("knn.tiles.armed")
                 # the staged slot may be DONATED to the program — the
                 # overflow fallback must never re-read it, so the
                 # handle keeps host f32 copies (same values; the

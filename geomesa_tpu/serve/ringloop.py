@@ -11,11 +11,11 @@ setup cost.
 
 One **ring program** per (type, canonical CQL, hints, k, impl,
 Q-bucket[, mesh_shape]) window class (planner.ring_arm): the plan, the
-resident superbatch, the f64-exact filter mask, the calibrated sparse
-capacity, the fused-count scalar and the AOT executable are all frozen
-at arm time under the ExecutableRegistry's ring tier
-(`<kernel>@ring{depth}[+donate]` — depth and the donation contract key
-the entry). Query inputs live in a fixed ring of `depth` staging slots
+resident superbatch, the f64-exact filter mask (with its sparse tile
+list on the single-chip route), the calibrated sparse capacity, the
+fused-count scalar and the AOT executable are all frozen at arm time
+under the ExecutableRegistry's ring tier (`<kernel>@ring{depth}[+donate]`
+— depth and the donation contract key the entry). Query inputs live in a fixed ring of `depth` staging slots
 (engine.device.QueryStager generalized to depth R); with donation on
 (non-CPU backends) each slot's buffer is consumed by its window's
 program and XLA reuses that HBM across the rotation, so the device
@@ -27,7 +27,8 @@ window the Python work is ONLY
     dispatch       ONE pre-compiled executable invocation
     harvest        the completer thread's combined sync read
 
-— no plan, no residency walk, no mask recompute, no tracing, no new
+— no plan, no residency walk, no mask recompute (the mesh route still
+lists each shard's match-bearing tiles per window), no tracing, no new
 executions compiled (zero recompiles asserted via JitTracker in
 tests/test_ringloop.py). `dispatches_per_window` (bench-serve
 `--mode sustained --ring`) meters

@@ -6,6 +6,8 @@ Parity oracle: NumPy f64 haversine + argpartition over the masked rows
 (tests/reference_engine.py style), the same oracle the bench gates on.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -13,7 +15,8 @@ import jax.numpy as jnp
 
 from geomesa_tpu.engine.geodesy import haversine_m_np
 from geomesa_tpu.engine.knn_scan import (
-    chord_blockmin, knn_fullscan, knn_fullscan_tiled, knn_sparse_scan)
+    DATA_TILE, chord_blockmin, knn_fullscan, knn_fullscan_tiled,
+    knn_ring_scan, knn_sparse_scan, knn_tile_summary)
 
 # tiny tiles: interpret mode executes the grid serially in Python — the
 # TPU-targeted tile sizes would take minutes per call on CPU
@@ -181,6 +184,93 @@ def test_sparse_dead_slots_never_duplicate_tile0():
     exp = oracle(qx, qy, x, y, mask, k)
     np.testing.assert_allclose(
         np.sort(fd, 1), exp, rtol=1e-4, atol=1.0)
+
+
+def _ring_scan(k, m_blocks):
+    import jax
+
+    return jax.jit(functools.partial(
+        knn_ring_scan, k=k, m_blocks=m_blocks, interpret=True))
+
+
+def _ring_mask(case, n):
+    mask = np.zeros(n, bool)
+    if case == "one_live_tile":
+        mask[2 * DATA_TILE + 100: 2 * DATA_TILE + 900] = True
+    elif case in ("every_tile_live", "overflow"):
+        mask[::97] = True
+    elif case == "unaligned":
+        mask[np.random.default_rng(5).random(n) < 0.3] = True
+    return mask
+
+
+class TestArmTimeTiles:
+    """The ring program's route: `knn_tile_summary` once, then the scan
+    half alone (`knn_ring_scan`), at the real tile sizes."""
+
+    @pytest.mark.parametrize("case,n,cap", [
+        ("empty", 4 * DATA_TILE, 4),
+        ("one_live_tile", 4 * DATA_TILE, 4),
+        ("every_tile_live", 4 * DATA_TILE, 4),
+        ("overflow", 4 * DATA_TILE, 2),
+        ("unaligned", 3 * DATA_TILE + 1000, 4),
+    ])
+    def test_ring_route_bit_identical_to_sparse_scan(self, case, n, cap):
+        _, _, _, _, _, dev = make(n, 4, seed=23)
+        mask = jnp.asarray(_ring_mask(case, n))
+        fd, fi, ov = knn_sparse_scan(
+            *dev[:4], mask, k=5, tile_capacity=cap, m_blocks=8,
+            interpret=True)
+        summary = knn_tile_summary(mask, tile_capacity=cap)
+        rd, ri, rov = _ring_scan(5, 8)(*dev[:4], *summary)
+        np.testing.assert_array_equal(np.asarray(rd), np.asarray(fd))
+        np.testing.assert_array_equal(np.asarray(ri), np.asarray(fi))
+        assert bool(rov) == bool(ov) == (case == "overflow")
+        maskf, tile_ids, n_sel, _ = summary
+        assert maskf.shape[0] % DATA_TILE == 0
+        live = np.flatnonzero(np.asarray(maskf).reshape(
+            -1, DATA_TILE).max(1) > 0)
+        assert int(n_sel) == len(live)
+        np.testing.assert_array_equal(
+            np.asarray(tile_ids)[:min(len(live), cap)], live[:cap])
+        # the ring's overflow fallback scans the padded f32 mask
+        dd, di = knn_fullscan(*dev[:4], maskf, k=5, m_blocks=8,
+                              interpret=True)
+        bd, bi = knn_fullscan(*dev[:4], mask, k=5, m_blocks=8,
+                              interpret=True)
+        np.testing.assert_array_equal(np.asarray(dd), np.asarray(bd))
+        np.testing.assert_array_equal(np.asarray(di), np.asarray(bi))
+
+    def test_ring_body_makes_no_pass_over_the_rows(self):
+        import jax
+        from jax.extend.core import ClosedJaxpr, Jaxpr
+
+        n = 4 * DATA_TILE
+        _, _, _, _, mask, dev = make(n, 4, seed=29)
+        summary = knn_tile_summary(jnp.asarray(mask), tile_capacity=4)
+        jaxpr = jax.make_jaxpr(functools.partial(
+            knn_ring_scan, k=5, m_blocks=8, interpret=True))(
+                *dev[:4], *summary)
+        seen = []
+
+        def walk(jx):
+            for eqn in jx.eqns:
+                if eqn.primitive.name in (
+                        "reduce_max", "convert_element_type", "gt"):
+                    seen.extend(
+                        (eqn.primitive.name, v.aval.shape)
+                        for v in eqn.invars
+                        if getattr(v.aval, "size", 0) >= n)
+                for p in eqn.params.values():
+                    for sub in (p if isinstance(p, (tuple, list))
+                                else (p,)):
+                        if isinstance(sub, ClosedJaxpr):
+                            walk(sub.jaxpr)
+                        elif isinstance(sub, Jaxpr):
+                            walk(sub)
+
+        walk(jaxpr.jaxpr)
+        assert seen == []
 
 
 class TestKnnExactRefine:

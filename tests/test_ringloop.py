@@ -73,6 +73,13 @@ def serial_oracle(store, qpts):
             for i in range(len(qpts))]
 
 
+def _counter(name):
+    from geomesa_tpu.utils.metrics import metrics
+
+    with metrics._lock:
+        return metrics.counters.get(name, 0.0)
+
+
 def _sequential(store, qpts, config, lo=0, hi=WINDOWS, svc=None):
     """`hi - lo` consecutive single-request windows (each resolves
     before the next submits — the steady-state serve shape the ring
@@ -97,8 +104,10 @@ class TestRingIdentity:
         """Acceptance: K>=16 consecutive windows, ring vs serial vs
         ring-off pipelined — identical bits, every window past warmup
         on ONE armed program with zero fallbacks."""
+        armed0 = _counter("knn.tiles.armed")
         ring_res, ring_p = _sequential(
             store, qpts, ServeConfig(max_wait_ms=1.0))
+        armed = _counter("knn.tiles.armed") - armed0
         pipe_res, pipe_p = _sequential(
             store, qpts, ServeConfig(max_wait_ms=1.0, ring=False))
         for i in range(WINDOWS):
@@ -111,6 +120,8 @@ class TestRingIdentity:
             np.testing.assert_array_equal(ix, pix, err_msg=f"knn {i}")
         ring = ring_p["ring"]
         assert ring["windows"] == WINDOWS
+        # every ring window scanned the arm-time tile list
+        assert armed == ring["windows"]
         assert ring["armed"] == 1 and ring["programs"] == 1
         assert ring["fallbacks"] == {}
         assert "ring" not in pipe_p
@@ -224,12 +235,26 @@ class TestRingFallbacks:
                     "served", CQL, pts[i:i + 1, 0], pts[i:i + 1, 1],
                     k=5).result(timeout=300))
             st1 = svc.stats()["pipeline"]["ring"]
+            (prog,) = svc.pipeline.ring._programs.values()
         finally:
             svc.close(drain=True)
         assert st1["fallbacks"].get("stale", 0) >= 1
         assert st1["armed"] >= st0["armed"] + 1  # re-armed post-write
         # exactness against a fresh serial replay over the grown store
         planner = ds.get_feature_source("served").planner
+        # the re-armed program's tile list is the new mask's, not the
+        # stale one's
+        from geomesa_tpu.engine.knn_scan import knn_tile_summary
+
+        plan = planner.plan(Query("served", CQL))
+        mask = planner._knn_mask_setup(plan, plan.query)[3]
+        maskf, tile_ids, n_sel, _ = knn_tile_summary(
+            mask, tile_capacity=prog.cap)
+        np.testing.assert_array_equal(np.asarray(prog.mask),
+                                      np.asarray(maskf))
+        np.testing.assert_array_equal(np.asarray(prog.tile_ids),
+                                      np.asarray(tile_ids))
+        assert int(prog.n_sel) == int(n_sel)
         for j, i in enumerate(range(4, 8)):
             sd, six, _ = planner.knn(
                 Query("served", CQL), pts[i:i + 1, 0], pts[i:i + 1, 1],

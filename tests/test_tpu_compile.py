@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import pytest
 
@@ -85,6 +86,29 @@ def test_chord_blockmin_sparse(spec):
     _compiled_text(chord_blockmin_sparse, spec((Q,)), spec((Q,)),
                    spec((N,)), spec((N,)), spec((N,)),
                    spec((cap,), jnp.int32), spec((), jnp.int32))
+
+
+def test_knn_ring_scan_makes_no_pass_over_the_rows(spec):
+    """The ring program's window (the scan over an arm-time tile
+    summary): the N-row arrays appear only as parameters and free
+    bitcasts, never as a reshape copy, a compare or a reduction. At
+    the ais cell's padded row count: at a few MB the compiler stages
+    whole arrays into fast memory, which a served store never fits."""
+    from geomesa_tpu.engine.knn_scan import DATA_TILE, knn_ring_scan
+
+    n, cap = 3648 * DATA_TILE, 1024
+    text = _compiled_text(
+        functools.partial(knn_ring_scan, k=10, m_blocks=64,
+                          interpret=False),
+        spec((8,)), spec((8,)), spec((n,)), spec((n,)), spec((n,)),
+        spec((cap,), jnp.int32), spec((), jnp.int64),
+        spec((), jnp.bool_))
+    whole = re.compile(
+        rf"= \w+\[({n}|{n // 128},128|{n // DATA_TILE},{DATA_TILE})\]")
+    ops = [line for line in text.splitlines()
+           if whole.search(line) and " bitcast(" not in line
+           and " parameter(" not in line]
+    assert ops == []
 
 
 @pytest.mark.parametrize("capd", [64, 512])
