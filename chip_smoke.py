@@ -11,9 +11,12 @@ every answer against an independent host oracle:
   - a 512x512 density query through the `density_*` hints has a mass
     equal to that count;
   - a started `QueryService` answers the same count and kNN as
-    concurrent requests, equal to the direct answers.
+    concurrent requests, equal to the direct answers;
+  - the Mosaic kernels outside that path (the polygon-layer join and
+    assignment, z-sparse density, the pruned TubeSelect) at 2^20 rows
+    against an oracle each (`kernel_gates`).
 
-The whole set runs twice in this one process: cold, then again after
+The path's checks run twice in this one process: cold, then again after
 `jax.clear_caches()`, so the second pass compiles from the persistent
 compilation cache and its compile seconds show against the first's.
 
@@ -148,6 +151,98 @@ def host_oracle(batch, qx, qy) -> dict:
     log(f"host oracle: {int(mask.sum())} matching rows, "
         f"{time.perf_counter() - t:.1f}s")
     return {"count": int(mask.sum()), "knn": knn}
+
+
+# -- kernel gates ------------------------------------------------------------
+
+
+def crossings_f64(px, py, x1, y1, x2, y2) -> np.ndarray:
+    """[points, edges]: the edge crosses the ray from the point towards
+    +x (the f64 crossing-number test)."""
+    cond = (y1[None] <= py[:, None]) != (y2[None] <= py[:, None])
+    t = (py[:, None] - y1[None]) / np.where(y2 == y1, 1.0, y2 - y1)[None]
+    return cond & (x1[None] + t * (x2 - x1)[None] > px[:, None])
+
+
+def kernel_gates(rows: int = 1 << 20, pip_points: int = 1 << 15,
+                 grid: int = 256, seed: int = 97) -> dict:
+    """Each kernel against an oracle on seeded data: `pip_layer` and
+    `pip_layer_assign` (two disjoint 700-edge polygons, `pip_points`
+    points; the f64 oracle is [points, edges]) against `crossings_f64`;
+    `density_zsparse` over Z-ordered rows against the scatter kernel
+    `density_grid` (equal `grid` x `grid` counts, with a non-empty
+    sparse tile list); `tube_select_pruned` against the dense
+    `tube_select` (equal masks). Returns {gate: passed}."""
+    import jax.numpy as jnp
+
+    from geomesa_tpu.curve.z2 import Z2SFC
+    from geomesa_tpu.engine.density import density_grid
+    from geomesa_tpu.engine.density_zsparse import density_zsparse
+    from geomesa_tpu.engine.knn_scan import default_interpret
+    from geomesa_tpu.engine.pip_sparse import pip_layer, pip_layer_assign
+    from geomesa_tpu.engine.tube import tube_select, tube_select_pruned
+
+    interpret = default_interpret()
+    rng = np.random.default_rng(seed)
+    gates = {}
+
+    th = np.linspace(0, 2 * np.pi, 700, endpoint=False)
+    x1 = np.concatenate([10 * np.cos(th) - 20, 8 * np.cos(th) + 15])
+    y1 = np.concatenate([10 * np.sin(th), 12 * np.sin(th) + 5])
+    x2 = np.concatenate([np.roll(x1[:700], -1), np.roll(x1[700:], -1)])
+    y2 = np.concatenate([np.roll(y1[:700], -1), np.roll(y1[700:], -1)])
+    pol = np.repeat(np.arange(2, dtype=np.int64), 700)
+    px = np.sort(rng.uniform(-35, 30, pip_points))
+    py = rng.uniform(-15, 20, pip_points)
+    cross = crossings_f64(px, py, x1, y1, x2, y2)
+    inside, _ = pip_layer(px, py, x1, y1, x2, y2, pol, interpret=interpret)
+    gates["pip_layer"] = bool(
+        (inside == (cross.sum(1) % 2 == 1)).all())
+    pid, _, _ = pip_layer_assign(px, py, x1, y1, x2, y2, pol,
+                                 interpret=interpret)
+    want = np.full(pip_points, -1, np.int64)
+    for p in (0, 1):
+        want[cross[:, pol == p].sum(1) % 2 == 1] = p
+    gates["pip_assign"] = bool((pid == want).all())
+
+    x = np.sort(rng.uniform(-60, 60, rows))
+    y = rng.uniform(-40, 40, rows)
+    mask = (x > -20) & (x < 20) & (rng.random(rows) < 0.5)
+    # Z order: x-sorted rows would send every tile to the dense
+    # fallback and never compile the sparse kernel
+    zo = np.argsort(Z2SFC().index(x, y))
+    zx = jnp.asarray(x[zo], jnp.float32)
+    zy = jnp.asarray(y[zo], jnp.float32)
+    ones = jnp.ones(rows, jnp.float32)
+    dmask = jnp.asarray(rng.random(rows) < 0.8)
+    bbox = (-60.0, -40.0, 60.0, 40.0)
+    g1, calib = density_zsparse(zx, zy, ones, dmask, bbox, grid, grid,
+                                interpret=interpret)
+    g2 = density_grid(zx, zy, ones, dmask, bbox, grid, grid)
+    gates["density_zsparse"] = bool(
+        np.array_equal(np.asarray(g1), np.asarray(g2))
+        and len(calib.tile_ids) > 0)
+
+    t = rng.integers(0, 86_400_000, rows)
+    targs = (jnp.asarray(x, jnp.float32), jnp.asarray(y, jnp.float32),
+             jnp.asarray(t, jnp.int64), jnp.asarray(mask),
+             jnp.asarray(np.linspace(-30, 10, 64), jnp.float32),
+             jnp.asarray(np.linspace(-20, 20, 64), jnp.float32),
+             jnp.asarray(np.linspace(0, 86_400_000, 64), jnp.int64),
+             jnp.float32(50_000.0), jnp.int64(3_600_000))
+    dense = np.asarray(tube_select(*targs))
+    pruned, _ = tube_select_pruned(*targs)
+    gates["tube_pruned"] = bool(
+        np.array_equal(np.asarray(pruned), dense) and dense.any())
+    return gates
+
+
+def kernel_phase() -> dict:
+    t = time.perf_counter()
+    gates = kernel_gates()
+    for name, ok in gates.items():
+        check(ok, f"kernel gate {name}")
+    return {"gates": gates, "wall_s": time.perf_counter() - t}
 
 
 # -- compile accounting ------------------------------------------------------
@@ -378,10 +473,12 @@ def one_chip(args) -> None:
     for name in ("serve.oom.hosteval", "serve.ring.fallbacks"):
         v = counter(name)
         check(v == 0, f"{name} = {v:g}")
+    kernels = kernel_phase()
+    log(f"kernel gates: {kernels['wall_s']:.1f}s")
     print(json.dumps({
         "rows": args.rows, "bytes_in_use": res["bytes_in_use"],
         "partitions": res["partitions"], "compile_cache_dir": cache_dir,
-        "count": oracle["count"], "passes": passes,
+        "count": oracle["count"], "passes": passes, "kernels": kernels,
     }))
 
 

@@ -363,7 +363,7 @@ def _discipline(mod: ModInfo, ci: ClassInfo) -> _Discipline:
 class ConcurrencyIndex:
     """Thread-entry reachability + attribute-call-site guard map + the
     lock acquisition graph, computed once per lint run over scan AND
-    reference modules (a thread started in `bench.py` makes package code
+    reference modules (a thread started in a script makes package code
     thread-reachable just like one started inside the package)."""
 
     def __init__(self, modules: List[ModInfo]):

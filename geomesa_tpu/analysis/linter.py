@@ -6,7 +6,7 @@ the tests all go through it). The scan set is what gets linted; the
 jit-name resolution) additionally pulls in every other .py file under the
 repo root (pyproject.toml discoverable above the scan path) — a jitted
 kernel linted in isolation whose callers live in `plan/`, `tests/` or
-`bench.py` is an API, not a corpse.
+`scripts/` is an API, not a corpse.
 """
 
 from __future__ import annotations

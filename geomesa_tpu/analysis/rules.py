@@ -279,7 +279,7 @@ def _gt04_events(mod: ModInfo, fn: ast.FunctionDef):
         if callee is None:
             continue
         if callee.lstrip("_").startswith("sync"):
-            # sync()/_sync() wrapper idiom (bench.py, scripts/_util.py)
+            # sync()/_sync() wrapper idiom of timing scripts
             yield ("sync", line, callee)
             continue
         resolved = _resolve_local_def(mod, node, callee)
@@ -331,7 +331,7 @@ def _resolve_local_def(mod: ModInfo, call: ast.Call, name: str):
     last definition at or before the call line). Returns
     (def_node, is_jit) or None. This is what keeps a nested plain
     `run()` closure distinct from a module-level `run = jax.jit(...)`
-    three functions away (bench.py's shape)."""
+    three functions away (a timing script's shape)."""
     cands = _local_defs(mod).get(name)
     if not cands:
         return None

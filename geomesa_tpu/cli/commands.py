@@ -122,22 +122,6 @@ def register(sub: "argparse._SubParsersAction") -> None:
          (["--format", "-F"], {"default": "csv",
           "choices": ["csv", "json"], "help": "output format"})],
     )
-    cmd(
-        "bench", "run a BASELINE benchmark config",
-        _bench,
-        [(["--config"], {"type": int, "default": 3,
-          "choices": [1, 2, 3, 4, 5, 6],
-          "help": "BASELINE.json config (3 = headline BBOX+time+kNN, "
-                  "6 = polygon density)"}),
-         (["--smoke"], {"action": "store_true",
-          "help": "small sizes, force CPU"}),
-         (["--dist"], {"choices": ["uniform", "clustered"],
-          "default": "uniform",
-          "help": "configs 3/4: data distribution"}),
-         (["--cold"], {"action": "store_true",
-          "help": "config 1: also time the parquet->device cold path"}),
-         (["--n"], {"type": int, "default": None, "help": "points"})],
-    )
 
     # serve subsystem (docs/SERVING.md): concurrent query serving with
     # admission control + request coalescing
@@ -247,106 +231,6 @@ def register(sub: "argparse._SubParsersAction") -> None:
                              "serve`")
     warm_p.set_defaults(func=_warmup)
 
-    bserve_p = sub.add_parser(
-        "bench-serve", help="serving load generator: open/closed-loop "
-                            "workloads, p50/p95/p99 + coalescing report")
-    bserve_p.add_argument("--catalog", "-c", default=None,
-                          help="existing catalog (default: synthesize a "
-                               "throwaway store)")
-    bserve_p.add_argument("--feature-name", "-f", default=None,
-                          help="feature type (required with --catalog)")
-    bserve_p.add_argument("--n", type=int, default=20000,
-                          help="synthetic store size (no --catalog)")
-    bserve_p.add_argument("--kind", default="knn",
-                          choices=["knn", "count"], help="workload kind")
-    bserve_p.add_argument("--k", type=int, default=8, help="kNN k")
-    bserve_p.add_argument("--mode", default="closed",
-                          choices=["closed", "open", "sustained",
-                                   "subscribe", "approx", "wire"])
-    bserve_p.add_argument("--wire-rows", type=int, default=100_000,
-                          help="wire mode: rows per bulk execute "
-                               "response (the JSON-vs-columnar encode "
-                               "comparison; docs/SERVING.md "
-                               "\"Columnar wire\")")
-    bserve_p.add_argument("--push-sinks", type=int, default=1000,
-                          help="wire mode: fan-out subscriber count "
-                               "(one encode per frame, asserted)")
-    bserve_p.add_argument("--tolerance", type=float, default=0.1,
-                          help="approx mode: tolerant clients' accuracy "
-                               "contract (bound <= tolerance * answer)")
-    bserve_p.add_argument("--subs", type=int, default=8,
-                          help="subscribe mode: standing subscriptions "
-                               "(bbox/dwithin geofences + density "
-                               "windows, cycling)")
-    bserve_p.add_argument("--batches", type=int, default=20,
-                          help="subscribe mode: kafka batches folded")
-    bserve_p.add_argument("--lanes", action="store_true",
-                          help="subscribe mode: vmapped-lane vs "
-                               "fused-slot comparison at "
-                               "S in {64, 1024, 8192} (docs/SERVING.md "
-                               "\"Standing queries\"); the fused leg "
-                               "is capped at S<=1024 — its compile "
-                               "cost grows super-linearly with S")
-    bserve_p.add_argument("--rows", type=int, default=64,
-                          help="subscribe mode: rows per kafka batch")
-    bserve_p.add_argument("--clients", type=int, default=16,
-                          help="closed-loop client count")
-    bserve_p.add_argument("--rate", type=float, default=200.0,
-                          help="open-loop offered rate (qps)")
-    bserve_p.add_argument("--outstanding", type=int, default=32,
-                          help="sustained-mode in-flight request cap "
-                               "(semaphore-gated closed loop reporting "
-                               "pts/s + windows-in-flight)")
-    bserve_p.add_argument("--no-pipeline", action="store_true",
-                          help="serial dispatch (pipelined is the "
-                               "default for kNN windows)")
-    bserve_p.add_argument("--ring", action="store_true",
-                          help="sustained mode: also run a ring-off "
-                               "(pipelined) baseline and report the "
-                               "dispatches_per_window ratio — the "
-                               "persistent serve loop's headline "
-                               "(docs/SERVING.md \"Persistent serve "
-                               "loop\")")
-    bserve_p.add_argument("--no-ring", action="store_true",
-                          help="disable the persistent serve loop for "
-                               "the measured run (ring programs are "
-                               "the default for eligible kNN windows)")
-    bserve_p.add_argument("--duration", type=float, default=5.0,
-                          help="seconds per measured run")
-    bserve_p.add_argument("--max-wait-ms", type=float, default=2.0)
-    bserve_p.add_argument("--max-batch", type=int, default=64)
-    bserve_p.add_argument("--no-compare", action="store_true",
-                          help="skip the serial (coalescing-off) baseline")
-    bserve_p.add_argument("--mesh", default="auto", metavar="auto|N|off",
-                          help="sharded serving for the measured run "
-                               "(docs/SERVING.md): auto = all devices "
-                               "when >1; N = first N devices; off = "
-                               "single-chip. When a mesh resolves, the "
-                               "comparison adds a same-stack single-"
-                               "chip run and reports mesh_speedup + "
-                               "per-shard pts/s")
-    bserve_p.add_argument("--fleet", type=int, default=None,
-                          metavar="N",
-                          help="serve through an N-replica fleet "
-                               "router instead of one service: "
-                               "closed-loop clients over the wire, "
-                               "one replica killed abruptly at "
-                               "half-time, report p99-during-kill + "
-                               "zero-drop accounting, and compare "
-                               "against a single-replica run "
-                               "(docs/SERVING.md \"Replica fleets\")")
-    bserve_p.add_argument("--no-kill", action="store_true",
-                          help="fleet mode: skip the scripted "
-                               "replica kill")
-    bserve_p.add_argument("--smoke", action="store_true",
-                          help="small sizes for CI")
-    bserve_p.add_argument("--trace", default=None, metavar="OUT.json",
-                          help="trace the measured runs and write a "
-                               "Perfetto trace_event JSON here; also "
-                               "prints the dispatch-gap report line "
-                               "(docs/OBSERVABILITY.md)")
-    bserve_p.set_defaults(func=_bench_serve)
-
     prof_p = sub.add_parser(
         "prof", help="continuous serve profile: lifetime per-phase/"
                      "per-kernel/per-shard distributions from a "
@@ -391,8 +275,7 @@ def register(sub: "argparse._SubParsersAction") -> None:
     trace_p.add_argument("--input", "-i", required=True,
                          help="trace file: a flight-recorder dump "
                               "(gmtpu serve --flight-dump, /debug/* "
-                              "saved to disk) or Perfetto JSON "
-                              "(bench-serve --trace)")
+                              "saved to disk) or Perfetto JSON")
     trace_p.add_argument("--gap", action="store_true",
                          help="print the dispatch-gap report (host-gap "
                               "vs kernel-time attribution)")
@@ -610,555 +493,6 @@ def _serve(args) -> int:
 
         print(metrics.to_prometheus(), file=sys.stderr)
     return 0
-
-
-def _bench_serve(args) -> int:
-    import contextlib
-    import tempfile
-
-    import numpy as np
-
-    from geomesa_tpu.plan import DataStore
-    from geomesa_tpu.serve.loadgen import (
-        count_request_factory, knn_request_factory, run_closed_loop,
-        run_open_loop, run_sustained)
-    from geomesa_tpu.serve.service import QueryService, ServeConfig
-
-    if args.smoke:
-        args.n = min(args.n, 2000)
-        args.duration = min(args.duration, 2.0)
-        args.clients = min(args.clients, 8)
-        args.subs = min(args.subs, 4)
-        args.batches = min(args.batches, 6)
-        args.rows = min(args.rows, 32)
-    if args.smoke and args.mode == "wire":
-        args.wire_rows = min(args.wire_rows, 20_000)
-        args.push_sinks = min(args.push_sinks, 128)
-    if args.mode == "subscribe":
-        if args.lanes:
-            return _bench_subscribe_lanes(args)
-        return _bench_subscribe(args)
-    if args.mode == "approx":
-        return _bench_approx(args)
-    if args.mode == "wire":
-        return _bench_wire(args)
-    if getattr(args, "fleet", None):
-        return _bench_fleet(args)
-    with contextlib.ExitStack() as stack:
-        if args.catalog:
-            if not args.feature_name:
-                print("error: --catalog needs --feature-name",
-                      file=sys.stderr)
-                return 2
-            store = DataStore(args.catalog, use_device_cache=True)
-            type_name = args.feature_name
-        else:
-            from geomesa_tpu.core.columnar import FeatureBatch
-            from geomesa_tpu.core.sft import SimpleFeatureType
-
-            tmp = stack.enter_context(tempfile.TemporaryDirectory())
-            rng = np.random.default_rng(11)
-            sft = SimpleFeatureType.from_spec(
-                "bench", "name:String,score:Double,dtg:Date,*geom:Point")
-            store = DataStore(tmp, use_device_cache=True)
-            src = store.create_schema(sft)
-            src.write(FeatureBatch.from_pydict(sft, {
-                "name": rng.choice(["a", "b", "c"], args.n).tolist(),
-                "score": rng.uniform(-10, 10, args.n),
-                "dtg": rng.integers(
-                    1_590_000_000_000, 1_600_000_000_000, args.n),
-                "geom": np.stack([rng.uniform(-170, 170, args.n),
-                                  rng.uniform(-80, 80, args.n)], 1),
-            }))
-            type_name = "bench"
-        cql = "BBOX(geom, -180, -90, 180, 90)"
-        if args.kind == "knn":
-            factory = knn_request_factory(type_name, cql, k=args.k)
-        else:
-            factory = count_request_factory(type_name, [
-                cql, "BBOX(geom, -60, -30, 60, 30)",
-                "BBOX(geom, 0, 0, 90, 45)"])
-        # warm the jit caches + device cache outside the measured window
-        warm = QueryService(store, ServeConfig(max_wait_ms=0.0))
-        warm.submit(factory(0)).result(timeout=300)
-        warm.close()
-
-        tracing = getattr(args, "trace", None)
-        if tracing:
-            # trace only the measured runs (warmup spans would pollute
-            # the gap attribution with deliberate cold-path compiles)
-            from geomesa_tpu.telemetry import RECORDER, TRACER
-
-            RECORDER.clear()
-            TRACER.enable()
-
-        try:
-            store_points = store.get_feature_source(
-                type_name).storage.count
-        except Exception:
-            store_points = args.n if not args.catalog else 0
-
-        pipe = not getattr(args, "no_pipeline", False)
-
-        def run(label: str, config: ServeConfig):
-            from geomesa_tpu.serve.loadgen import mesh_dispatch_count
-
-            svc = QueryService(store, config)
-            mesh_c0 = mesh_dispatch_count()
-            try:
-                if args.mode == "closed":
-                    rep = run_closed_loop(
-                        svc, factory, concurrency=args.clients,
-                        duration_s=args.duration)
-                elif args.mode == "open":
-                    rep = run_open_loop(
-                        svc, factory, rate_qps=args.rate,
-                        duration_s=args.duration)
-                else:
-                    rep = run_sustained(
-                        svc, factory, duration_s=args.duration,
-                        max_outstanding=args.outstanding,
-                        points_per_query=store_points)
-                if (svc.mesh is not None and not rep.mesh_devices
-                        and args.mode in ("closed", "open")
-                        and mesh_dispatch_count() > mesh_c0):
-                    # closed/open modes: still report the topology —
-                    # but only when windows actually took a mesh route
-                    # (run_sustained applies the same gate itself)
-                    rep.mesh_devices = int(svc.mesh.devices.size)
-            finally:
-                svc.close(drain=True)
-            doc = {"run": label, **rep.to_json()}
-            print(json.dumps(doc))
-            return rep
-
-        mesh_spec = getattr(args, "mesh", "off")
-        ring_on = not getattr(args, "no_ring", False)
-        if getattr(args, "ring", False) and not ring_on:
-            # --ring measures the ring against a ring-off baseline; a
-            # ring-disabled measured run would report a ~1.0 ratio that
-            # reads as "no benefit" instead of the conflict it is
-            print("error: --ring and --no-ring conflict", file=sys.stderr)
-            return 2
-        coalesced = run("coalesced", ServeConfig(
-            max_batch=args.max_batch, max_wait_ms=args.max_wait_ms,
-            pipeline=pipe, ring=ring_on, mesh=mesh_spec))
-        if getattr(args, "ring", False) and args.mode == "sustained":
-            # the persistent-serve-loop headline (docs/SERVING.md
-            # "Persistent serve loop"): identical sustained workload,
-            # ring OFF — per-window dispatch count must be strictly
-            # higher there
-            ringless = run("pipelined_baseline", ServeConfig(
-                max_batch=args.max_batch, max_wait_ms=args.max_wait_ms,
-                pipeline=pipe, ring=False, mesh=mesh_spec))
-            doc = {
-                "run": "ring_comparison",
-                "ring_dispatches_per_window":
-                    coalesced.dispatches_per_window,
-                "pipelined_dispatches_per_window":
-                    ringless.dispatches_per_window,
-                "ring_windows": coalesced.ring_windows,
-                "ring_fallbacks": coalesced.ring_fallbacks,
-            }
-            if ringless.dispatches_per_window > 0:
-                doc["dispatch_ratio"] = round(
-                    coalesced.dispatches_per_window
-                    / ringless.dispatches_per_window, 3)
-            print(json.dumps(doc))
-        if not args.no_compare:
-            single = None
-            if coalesced.mesh_devices > 1:
-                # the mesh multiplier the ROADMAP item-1 claim is
-                # judged on: same serve stack (coalescing + pipeline),
-                # mesh off — sharded-vs-single-chip on the same store
-                single = run("single_chip", ServeConfig(
-                    max_batch=args.max_batch,
-                    max_wait_ms=args.max_wait_ms, pipeline=pipe,
-                    mesh="off"))
-            # the serial baseline drops BOTH levers (coalescing and the
-            # pipeline) — and the mesh, when one was measured — so the
-            # comparison is serve-stack vs bare serial single-chip
-            serial = run("serial", ServeConfig(
-                max_batch=1, max_wait_ms=0.0, pipeline=False,
-                mesh="off" if coalesced.mesh_devices > 1 else None))
-            if serial.throughput_qps > 0:
-                doc = {
-                    "run": "comparison",
-                    "throughput_speedup": round(
-                        coalesced.throughput_qps / serial.throughput_qps,
-                        3),
-                    "p99_ratio": round(
-                        coalesced.p99_ms / serial.p99_ms, 3)
-                    if serial.p99_ms else None,
-                }
-                if args.mode == "sustained":
-                    doc["sustained_pts_per_s"] = round(
-                        coalesced.pts_per_s, 1)
-                    doc["windows_in_flight_max"] = \
-                        coalesced.windows_in_flight_max
-                if coalesced.mesh_devices:
-                    doc["mesh_devices"] = coalesced.mesh_devices
-                    if coalesced.per_shard_pts_per_s:
-                        # sustained mode only — closed/open report
-                        # topology but have no pts/s to normalize, and
-                        # a 0.0 here would read as a measured headline
-                        doc["per_shard_pts_per_s"] = round(
-                            coalesced.per_shard_pts_per_s, 1)
-                if single is not None:
-                    base = (single.pts_per_s
-                            if args.mode == "sustained"
-                            else single.throughput_qps)
-                    over = (coalesced.pts_per_s
-                            if args.mode == "sustained"
-                            else coalesced.throughput_qps)
-                    if base > 0:
-                        doc["mesh_speedup"] = round(over / base, 3)
-                print(json.dumps(doc))
-        if tracing:
-            # BENCH r06+ carries the dispatch-gap attribution: one JSON
-            # line next to the throughput lines, plus a Perfetto file
-            # for the flame view (ui.perfetto.dev)
-            from geomesa_tpu.telemetry import (
-                RECORDER, TRACER, gap_report, to_perfetto)
-
-            TRACER.disable()
-            traces = RECORDER.traces()
-            with open(tracing, "w") as f:
-                json.dump(to_perfetto(traces), f)
-            rec = RECORDER.stats()
-            print(json.dumps({
-                "run": "gap", "perfetto": tracing,
-                "traces_recorded": rec["trace_count"],
-                **gap_report(traces)}))
-    return 0
-
-
-def _bench_fleet(args) -> int:
-    """`gmtpu bench-serve --fleet N`: fleet-through-a-kill throughput
-    + p99, compared against a single replica (no kill). The headline
-    acceptance: the fleet keeps serving through the kill with p99
-    bounded and ZERO dropped requests."""
-    import contextlib
-    import tempfile
-
-    import numpy as np
-
-    from geomesa_tpu.core.columnar import FeatureBatch
-    from geomesa_tpu.core.sft import SimpleFeatureType
-    from geomesa_tpu.plan import DataStore
-    from geomesa_tpu.serve.loadgen import run_fleet_bench
-
-    with contextlib.ExitStack() as stack:
-        if args.catalog:
-            if not args.feature_name:
-                print("error: --catalog needs --feature-name",
-                      file=sys.stderr)
-                return 2
-            catalog, type_name = args.catalog, args.feature_name
-        else:
-            catalog = stack.enter_context(tempfile.TemporaryDirectory())
-            rng = np.random.default_rng(11)
-            sft = SimpleFeatureType.from_spec(
-                "bench", "name:String,score:Double,dtg:Date,*geom:Point")
-            store = DataStore(catalog, use_device_cache=True)
-            store.create_schema(sft).write(FeatureBatch.from_pydict(sft, {
-                "name": rng.choice(["a", "b", "c"], args.n).tolist(),
-                "score": rng.uniform(-10, 10, args.n),
-                "dtg": rng.integers(
-                    1_590_000_000_000, 1_600_000_000_000, args.n),
-                "geom": np.stack([rng.uniform(-170, 170, args.n),
-                                  rng.uniform(-80, 80, args.n)], 1),
-            }))
-            del store
-            type_name = "bench"
-        kill = not getattr(args, "no_kill", False)
-        fleet = run_fleet_bench(
-            catalog, type_name, n_replicas=args.fleet,
-            duration_s=args.duration, clients=args.clients, k=args.k,
-            kill=kill)
-        print(json.dumps({"run": "fleet", **fleet}))
-        single = None
-        if not args.no_compare and args.fleet > 1:
-            single = run_fleet_bench(
-                catalog, type_name, n_replicas=1,
-                duration_s=args.duration, clients=args.clients,
-                k=args.k, kill=False)
-            print(json.dumps({"run": "single_replica", **single}))
-        comparison = {
-            "run": "comparison",
-            "dropped": fleet["dropped"],
-            "untyped": fleet["untyped"],
-            "served_through_kill": fleet.get("served_during_kill", 0),
-            "p99_during_kill_ms": fleet.get("p99_during_kill_ms"),
-        }
-        if single is not None and single["throughput_qps"] > 0:
-            comparison["fleet_speedup"] = round(
-                fleet["throughput_qps"] / single["throughput_qps"], 3)
-        print(json.dumps(comparison))
-        # the acceptance contract, machine-checkable: zero drops, zero
-        # un-typed errors, and — when a kill happened — the fleet
-        # demonstrably served inside the kill window
-        ok = (fleet["dropped"] == 0 and fleet["untyped"] == 0
-              and (not fleet["killed"]
-                   or fleet.get("served_during_kill", 0) > 0))
-        return 0 if ok else 1
-
-
-def _bench_subscribe(args) -> int:
-    """`gmtpu bench-serve --mode subscribe`: N standing subscriptions
-    folded over M synthetic kafka batches; reports events/s and the
-    per-batch eval+push latency distribution (p50/p95/p99)."""
-    import numpy as np
-
-    from geomesa_tpu.core.columnar import FeatureBatch
-    from geomesa_tpu.core.sft import SimpleFeatureType
-    from geomesa_tpu.kafka.store import KafkaDataStore
-    from geomesa_tpu.serve.loadgen import run_subscribe
-
-    sft = SimpleFeatureType.from_spec(
-        "bench_live", "name:String,score:Double,dtg:Date,*geom:Point")
-    store = KafkaDataStore()
-    store.create_schema(sft)
-    n = args.rows
-
-    def make_batch(i: int) -> FeatureBatch:
-        # moving fleet: the same fid population drifts each batch, so
-        # geofence enter/exit churn is steady instead of append-only
-        rng = np.random.default_rng(997 * i + 13)
-        return FeatureBatch.from_pydict(sft, {
-            "name": rng.choice(["a", "b", "c"], n).tolist(),
-            "score": rng.uniform(-10, 10, n),
-            "dtg": rng.integers(
-                1_590_000_000_000, 1_600_000_000_000, n),
-            "geom": np.stack([rng.uniform(-60, 60, n),
-                              rng.uniform(-30, 30, n)], 1),
-        }, fids=[f"v{j}" for j in range(n)])
-
-    # seed the live layer; run_subscribe does its own warm fold (the
-    # fused-kernel AOT key is per evaluator+version, so only THIS
-    # manager's warm fold keeps the compile out of the measured window)
-    store.write("bench_live", make_batch(10_001))
-    rep = run_subscribe(store, "bench_live", make_batch,
-                        subscriptions=args.subs, batches=args.batches)
-    print(json.dumps({"run": "subscribe", **rep.to_json()}))
-    return 0
-
-
-def _bench_subscribe_lanes(args) -> int:
-    """`gmtpu bench-serve --mode subscribe --lanes`: the vmapped-lane
-    vs fused-slot comparison (docs/SERVING.md "Standing queries") at
-    S in {64, 1024, 8192} same-class bbox geofences. Each leg runs the
-    identical protocol — register-before-seed, first poll, steady
-    polls, one membership-churn event — on a fresh store, so events
-    match across modes and `speedup` is a wall-clock ratio. The fused
-    leg is capped at S<=1024: its trace+compile grows super-linearly
-    with S (~1 s at S=64, ~120 s at S=1024 on CPU CI), so beyond the
-    cap the sweep reports the lane leg only rather than extrapolating.
-    The verdict gates on the >=10x events/s floor at S=1024 and on
-    lane dispatches-per-poll staying S-independent (<=4 for one
-    geofence class)."""
-    import numpy as np
-
-    from geomesa_tpu.core.columnar import FeatureBatch
-    from geomesa_tpu.core.sft import SimpleFeatureType
-    from geomesa_tpu.kafka.store import KafkaDataStore
-    from geomesa_tpu.serve.loadgen import run_subscribe_lanes
-
-    sft = SimpleFeatureType.from_spec(
-        "bench_live", "name:String,score:Double,dtg:Date,*geom:Point")
-    n = args.rows
-
-    def make_store():
-        store = KafkaDataStore()
-        store.create_schema(sft)
-        return store
-
-    def make_batch(i: int) -> FeatureBatch:
-        rng = np.random.default_rng(997 * i + 13)
-        return FeatureBatch.from_pydict(sft, {
-            "name": rng.choice(["a", "b", "c"], n).tolist(),
-            "score": rng.uniform(-10, 10, n),
-            "dtg": rng.integers(
-                1_590_000_000_000, 1_600_000_000_000, n),
-            "geom": np.stack([rng.uniform(-60, 60, n),
-                              rng.uniform(-30, 30, n)], 1),
-        }, fids=[f"v{j}" for j in range(n)])
-
-    fused_cap = 1024
-    reports = {}
-    for s in (64, 1024, 8192):
-        rep = run_subscribe_lanes(
-            make_store, "bench_live", make_batch, subscriptions=s,
-            batches=4, fused=s <= fused_cap)
-        reports[s] = rep
-        print(json.dumps(rep), flush=True)
-    at_1024 = reports[1024]
-    verdict = {
-        "run": "lanes_verdict",
-        "speedup_at_1024": at_1024.get("speedup"),
-        "lane_dispatches_per_poll_at_8192":
-            reports[8192]["lanes"]["dispatches_per_poll"],
-        "floor": 10.0,
-    }
-    verdict["ok"] = bool(
-        (at_1024.get("speedup") or 0.0) >= verdict["floor"]
-        and verdict["lane_dispatches_per_poll_at_8192"] <= 4.0)
-    print(json.dumps(verdict))
-    return 0 if verdict["ok"] else 1
-
-
-def _bench_wire(args) -> int:
-    """`gmtpu bench-serve --mode wire`: the JSON-lines vs columnar
-    record-batch comparison over one bulk execute result (rows/s,
-    bytes/s, encode p50/p99) plus the PushMux fan-out (events/s at
-    --push-sinks subscribers, one encode per frame asserted). The
-    verdict gates on decoded-parity, the >=5x rows/s acceptance
-    floor, and the one-encode invariant."""
-    import contextlib
-    import tempfile
-
-    import numpy as np
-
-    from geomesa_tpu.plan import DataStore
-    from geomesa_tpu.serve import columnar as colwire
-    from geomesa_tpu.serve.loadgen import run_wire
-
-    if not colwire.have_pyarrow():
-        # typed skip, mirroring the wire smoke: a json-only host has
-        # nothing to compare — not a failure
-        print(json.dumps({"run": "wire", "skipped": True,
-                          "reason": "pyarrow_unavailable"}))
-        return 0
-    with contextlib.ExitStack() as stack:
-        if args.catalog:
-            if not args.feature_name:
-                print("error: --catalog needs --feature-name",
-                      file=sys.stderr)
-                return 2
-            store = DataStore(args.catalog, use_device_cache=True)
-            type_name = args.feature_name
-        else:
-            from geomesa_tpu.core.columnar import FeatureBatch
-            from geomesa_tpu.core.sft import SimpleFeatureType
-
-            tmp = stack.enter_context(tempfile.TemporaryDirectory())
-            n = max(args.n, args.wire_rows)
-            rng = np.random.default_rng(11)
-            sft = SimpleFeatureType.from_spec(
-                "bench", "name:String,score:Double,dtg:Date,*geom:Point")
-            store = DataStore(tmp, use_device_cache=True)
-            src = store.create_schema(sft)
-            src.write(FeatureBatch.from_pydict(sft, {
-                "name": rng.choice(["a", "b", "c"], n).tolist(),
-                "score": rng.uniform(-10, 10, n),
-                "dtg": rng.integers(
-                    1_590_000_000_000, 1_600_000_000_000, n),
-                "geom": np.stack([rng.uniform(-170, 170, n),
-                                  rng.uniform(-80, 80, n)], 1),
-            }))
-            type_name = "bench"
-        rep = run_wire(store, type_name, rows=args.wire_rows,
-                       push_sinks=args.push_sinks)
-        print(json.dumps({"run": "wire", **rep.to_json()}))
-        ok = (rep.wire_parity_ok
-              and rep.wire_speedup >= 5.0
-              and rep.push_encodes == rep.push_frames)
-        print(json.dumps({
-            "run": "wire_verdict", "ok": ok,
-            "speedup": round(rep.wire_speedup, 1),
-            "parity": rep.wire_parity_ok,
-            "one_encode": rep.push_encodes == rep.push_frames,
-            "push_events_per_s": round(rep.push_events_per_s)}))
-        return 0 if ok else 1
-
-
-def _bench_approx(args) -> int:
-    """`gmtpu bench-serve --mode approx`: tolerant vs exact count
-    clients over one synthetic (or supplied) store — the sketch-tier
-    speedup headline, tier shares, zero bound violations, and the
-    result-cache second-pass hit. The measured run disables the result
-    cache so `exact_p50` is the honest device-scan number; a short
-    second pass with the cache on reports the repeated-dashboard-query
-    hit rate."""
-    import contextlib
-    import tempfile
-
-    import numpy as np
-
-    from geomesa_tpu.plan import DataStore
-    from geomesa_tpu.serve.loadgen import run_approx
-    from geomesa_tpu.serve.service import QueryService, ServeConfig
-
-    with contextlib.ExitStack() as stack:
-        if args.catalog:
-            if not args.feature_name:
-                print("error: --catalog needs --feature-name",
-                      file=sys.stderr)
-                return 2
-            store = DataStore(args.catalog, use_device_cache=True)
-            type_name = args.feature_name
-        else:
-            from geomesa_tpu.core.columnar import FeatureBatch
-            from geomesa_tpu.core.sft import SimpleFeatureType
-
-            tmp = stack.enter_context(tempfile.TemporaryDirectory())
-            rng = np.random.default_rng(11)
-            sft = SimpleFeatureType.from_spec(
-                "bench", "name:String,score:Double,dtg:Date,*geom:Point")
-            store = DataStore(tmp, use_device_cache=True)
-            src = store.create_schema(sft)
-            src.write(FeatureBatch.from_pydict(sft, {
-                "name": rng.choice(["a", "b", "c"], args.n).tolist(),
-                "score": rng.uniform(-10, 10, args.n),
-                "dtg": rng.integers(
-                    1_590_000_000_000, 1_600_000_000_000, args.n),
-                "geom": np.stack([rng.uniform(-170, 170, args.n),
-                                  rng.uniform(-80, 80, args.n)], 1),
-            }))
-            type_name = sft.name
-        cqls = ["BBOX(geom, -180, -90, 180, 90)",
-                "BBOX(geom, -60, -30, 60, 30)",
-                "BBOX(geom, 0, 0, 90, 45)"]
-        planner = store.get_feature_source(type_name).planner
-        from geomesa_tpu.plan.query import Query
-
-        # exact oracle + warm (compiles, sketches, device cache) stay
-        # outside the measured window
-        exact_counts = {c: int(planner.count(Query(type_name, c)))
-                        for c in cqls}
-        svc = QueryService(store, ServeConfig(
-            max_wait_ms=args.max_wait_ms, result_cache=0))
-        try:
-            rep = run_approx(
-                svc, type_name, cqls, duration_s=args.duration,
-                clients=args.clients, tolerance=args.tolerance,
-                exact_counts=exact_counts)
-        finally:
-            svc.close(drain=True)
-        print(json.dumps({"run": "approx", **rep.to_json()}))
-        # second pass, cache ON: repeated exact queries must hit
-        svc2 = QueryService(store, ServeConfig(max_wait_ms=0.0))
-        try:
-            for c in cqls:
-                svc2.count(type_name, c).result(timeout=300)
-            for c in cqls:
-                svc2.count(type_name, c).result(timeout=300)
-            cache = svc2.stats().get("cache", {})
-        finally:
-            svc2.close(drain=True)
-        print(json.dumps({"run": "approx_cache_pass",
-                          "hits": cache.get("hits", 0),
-                          "misses": cache.get("misses", 0)}))
-        ok = (rep.bound_violations == 0 and rep.tier_sketch > 0
-              and cache.get("hits", 0) >= len(cqls))
-        print(json.dumps({
-            "run": "approx_verdict", "ok": ok,
-            "speedup_p50": round(rep.approx_speedup_p50, 1),
-            "bound_violations": rep.bound_violations,
-            "tiers": {"sketch": rep.tier_sketch,
-                      "cached": rep.tier_cached,
-                      "exact": rep.tier_exact}}))
-        return 0 if ok else 1
 
 
 def _top(args) -> int:
@@ -2183,35 +1517,6 @@ def _stats_topk(args) -> int:
     for value, count in stats.stats[0].result():
         print(f"{value}\t{count}")
     return 0
-
-
-def _bench(args) -> int:
-    """Run bench.py's configs through the CLI (upstream: the tools'
-    stats/benchmark-ish commands; here the BASELINE harness itself)."""
-    import importlib.util
-    import os
-
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.dirname(__file__))), "bench.py"
-    )
-    if not os.path.exists(path):
-        # bench.py lives at the repo root, next to the package — only a
-        # source checkout has it
-        raise FileNotFoundError(
-            "bench.py not found (the bench command needs a source checkout; "
-            f"looked at {path})"
-        )
-    spec = importlib.util.spec_from_file_location("geomesa_tpu_bench", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    argv = ["--config", str(args.config), "--dist", args.dist]
-    if args.smoke:
-        argv.append("--smoke")
-    if args.cold:
-        argv.append("--cold")
-    if args.n is not None:
-        argv += ["--n", str(args.n)]
-    return mod.main(argv)
 
 
 def _sql(args) -> int:

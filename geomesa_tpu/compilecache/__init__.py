@@ -8,7 +8,7 @@ start" section):
 
 - `enable_persistent_cache()` (persist.py): the library-level persistent
   XLA compilation cache shared by the planner, `QueryService`,
-  `gmtpu serve` and bench.py — executables survive process restarts.
+  `gmtpu serve` and the benchmark — executables survive process restarts.
 - `ExecutableRegistry` (registry.py): `jit(...).lower(abstract).compile()`
   AOT handles per (kernel, pow2 shape bucket, dtype, static-args) key,
   with opt-in buffer donation.

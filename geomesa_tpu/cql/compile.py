@@ -138,21 +138,6 @@ class CompiledFilter:
         """Host mask with borderline rows re-evaluated exactly in f64."""
         return self.refine(np.asarray(self.mask(dev, batch)), dev, batch)
 
-    def count_exact(
-        self, dev: DeviceBatch, batch: FeatureBatch, extra=None
-    ) -> int:
-        """Bit-exact match count WITHOUT fetching the full mask: the
-        device count is corrected by re-evaluating only the (few) band
-        rows in f64 on host. `extra` ANDs an additional device mask
-        (partition pruning / visibility) into both the count and the
-        band, so corrections respect it. One scalar + one small index
-        fetch; the f64-oracle-exact answer at device cost."""
-        m = self.mask(dev, batch)
-        if extra is not None:
-            m = m & extra
-        total = int(device_get(jnp.sum(m, dtype=jnp.int64)))
-        return total + self.band_count_correction(dev, batch, m, extra)
-
     def band_count_correction(
         self, dev: DeviceBatch, batch: FeatureBatch, m=None, extra=None
     ) -> int:

@@ -1125,8 +1125,7 @@ def _ets_of_tile(pl_, tiles, starts, ptid: int) -> np.ndarray:
 
 class LayerPrep(NamedTuple):
     """Everything the sparse kernels need, host-built once per layer
-    (the prepared-geometry/index analog; reused by bench.py so the bench
-    and the engine can never desynchronize)."""
+    (the prepared-geometry/index analog)."""
 
     pxp: np.ndarray
     pyp: np.ndarray
@@ -1369,40 +1368,6 @@ def prepare_layer_cached(
                 pass
     _prep_cache_put(key, prep)
     return prep
-
-
-def prepare_layer_async(
-    px_np, py_np, x1, y1, x2, y2, poly_of_edge,
-    margin: float = 1e-3, cache_dir: "str | None" = None,
-    key: "str | None" = None,
-):
-    """Kick the (cached) prep build onto a worker thread so the caller can
-    overlap it with device work that does not need pairs — point upload
-    and kernel warm-up (VERDICT r4 task 5's overlap half). Returns a
-    0-arg callable that joins and yields the LayerPrep. The build is pure
-    numpy, so the thread releases the GIL for the big vector ops."""
-    import threading
-
-    out: dict = {}
-
-    def work():
-        try:
-            out["prep"] = prepare_layer_cached(
-                px_np, py_np, x1, y1, x2, y2, poly_of_edge,
-                margin=margin, cache_dir=cache_dir, key=key)
-        except BaseException as e:  # re-raise on join
-            out["err"] = e
-
-    t = threading.Thread(target=work, daemon=True)
-    t.start()
-
-    def result() -> LayerPrep:
-        t.join()
-        if "err" in out:
-            raise out["err"]
-        return out["prep"]
-
-    return result
 
 
 def pip_layer(

@@ -515,8 +515,14 @@ def _mesh_phase(plan: FaultPlan, root: str, report: ChaosReport,
     import jax
 
     from geomesa_tpu.faults.plan import FaultRule
-    from geomesa_tpu.serve.loadgen import mesh_dispatch_count
     from geomesa_tpu.serve.service import QueryService, ServeConfig
+    from geomesa_tpu.utils.metrics import metrics
+
+    def mesh_dispatch_count() -> float:
+        # windows that ran a mesh route: whole-mesh programs plus
+        # shard-affinity local windows
+        return (metrics.count("knn.mesh.dispatches")
+                + metrics.count("knn.mesh.local_dispatches"))
 
     if len(jax.devices()) < 2:
         say("mesh phase: skipped (single device — no mesh to shard)")
@@ -576,9 +582,6 @@ def _mesh_phase(plan: FaultPlan, root: str, report: ChaosReport,
         # no per-chip meltdown: every surviving window still ran the
         # ONE-program mesh route (the outage neither wedged the mesh
         # nor silently degraded the service to single-chip)
-        # the shared route counter (whole-mesh + shard-affinity
-        # local windows — loadgen reports topology off the same
-        # signal, so the two can never disagree)
         survived = mesh_dispatch_count() - base_mesh
         if survived != _MESH_REQUESTS - 1:
             report.invariant_failures.append(

@@ -32,8 +32,7 @@ them:
 
 Certification: `gmtpu chaos --fleet` (faults/chaos.py) kills a replica
 mid-burst and asserts zero un-typed client errors and zero
-double-executed work; `gmtpu bench-serve --fleet N` measures the fleet
-serving straight through a replica kill.
+double-executed work.
 """
 
 from geomesa_tpu.fleet.health import (

@@ -129,8 +129,8 @@ class QueryPlanner:
     def _enable_compile_cache(self) -> None:
         """Library-level persistent compilation cache (compilecache/):
         idempotent and never-failing, so compiled predicate masks and
-        kernels survive process restarts for every planner consumer, not
-        just bench.py. Called from the EXECUTION entry points, not the
+        kernels survive process restarts for every planner consumer.
+        Called from the EXECUTION entry points, not the
         constructor — resolving the per-backend cache subdir initializes
         the jax backend (seconds on TPU), which metadata-only paths like
         `gmtpu explain` must never pay."""

@@ -20,17 +20,12 @@ from geomesa_tpu.serve.batcher import (
     compat_key, execute_batch, fused_count_key)
 from geomesa_tpu.serve.pipeline import DispatchPipeline
 from geomesa_tpu.serve.service import QueryService, ServeConfig, self_check
-from geomesa_tpu.serve.loadgen import (
-    LoadReport, count_request_factory, knn_request_factory,
-    run_closed_loop, run_open_loop, run_sustained, run_wire)
 from geomesa_tpu.serve.columnar import PushMux, wire_capabilities
 
 __all__ = [
     "PRIORITIES", "AdmissionQueue", "QueryRejected", "RateLimiter",
     "ServeRequest", "TokenBucket", "compat_key", "execute_batch",
     "fused_count_key", "DispatchPipeline",
-    "QueryService", "ServeConfig", "self_check", "LoadReport",
-    "knn_request_factory", "count_request_factory",
-    "run_closed_loop", "run_open_loop", "run_sustained", "run_wire",
+    "QueryService", "ServeConfig", "self_check",
     "PushMux", "wire_capabilities",
 ]

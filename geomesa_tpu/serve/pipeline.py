@@ -527,14 +527,6 @@ class DispatchPipeline:
 
     # -- introspection -----------------------------------------------------
 
-    def reset_max_inflight(self) -> None:
-        """Re-seed the windows-in-flight high-water mark at the current
-        depth — measurement loops (loadgen.run_sustained) call this at
-        run start so the reported peak is the RUN's, not the service
-        lifetime's."""
-        with self._lock:
-            self._max_inflight = self._inflight
-
     def stats(self) -> dict:
         with self._lock:
             out = {

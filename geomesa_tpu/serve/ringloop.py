@@ -30,11 +30,9 @@ window the Python work is ONLY
 — no plan, no residency walk, no mask recompute (the mesh route still
 lists each shard's match-bearing tiles per window), no tracing, no new
 executions compiled (zero recompiles asserted via JitTracker in
-tests/test_ringloop.py). `dispatches_per_window` (bench-serve
-`--mode sustained --ring`) meters
-exactly this: the ring route is strictly below the pipelined baseline
-on CPU CI, and on real TPU it is the structure BENCH r06 needs to hit
-sustained ≥700M pts/s.
+tests/test_ringloop.py). `dispatches_per_window` (the
+`serve.device.ops` delta over a run's windows) meters exactly this: the
+ring route is strictly below the pipelined baseline.
 
 Correctness contract:
 

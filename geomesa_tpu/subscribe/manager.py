@@ -1,7 +1,7 @@
 """SubscriptionManager: admission, push and lifecycle glue.
 
-The wire layer (serve/protocol.py `subscribe`/`unsubscribe` verbs) and
-the bench loadgen talk to THIS class; the registry holds state, the
+The wire layer (serve/protocol.py `subscribe`/`unsubscribe` verbs)
+talks to THIS class; the registry holds state, the
 evaluator folds deltas (one fused device dispatch per poll). Admission
 reuses the PR-2 serving fabric: per-tenant token buckets (the same
 RateLimiter the QueryService uses — pass the service's limiter in so

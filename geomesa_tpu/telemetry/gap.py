@@ -2,9 +2,9 @@
 
 ROADMAP item 2's finding — dispatch RTT (0.101s) exceeding net kernel
 time (0.066s) — came from one hand-instrumented bench run. This module
-derives the same attribution from any set of query traces, so every
-traced `bench-serve` run (and `gmtpu trace --gap` over a flight-recorder
-dump) reports exactly where the serve path's wall time went:
+derives the same attribution from any set of query traces, so
+`gmtpu trace --gap` over a flight-recorder dump or a Perfetto trace
+reports exactly where the serve path's wall time went:
 
 - **per-phase attribution**: total/mean/share for every span name
   (admit, queue.wait, dispatch, prepare, plan, residency,

@@ -194,6 +194,11 @@ class MetricsRegistry:
             key = self._key(name, labels)
             self.counters[key] = self.counters.get(key, 0.0) + inc
 
+    def count(self, name: str) -> float:
+        """The current value of counter `name` (0 before its first tick)."""
+        with self._lock:
+            return self.counters.get(name, 0.0)
+
     def gauge(self, name: str, value: float, **labels) -> None:
         with self._lock:
             self.gauges[self._key(name, labels)] = float(value)
@@ -317,8 +322,8 @@ def note_device_op(n: int = 1) -> None:
     """Meter `n` serve-path device interactions (a staged transfer, a
     kernel/program dispatch, a band-correction read, the combined sync
     read) into the `serve.device.ops` counter — the per-window dispatch
-    accounting `bench-serve`'s `dispatches_per_window` is derived from
-    (docs/SERVING.md "Persistent serve loop"). Centralized so every
+    accounting `dispatches_per_window` is derived from (docs/SERVING.md
+    "Persistent serve loop"). Centralized so every
     dispatch route (serial, pipelined, mesh, ring) increments through
     one seam and the ring-vs-pipeline comparison can never drift on
     counting convention."""

@@ -25,20 +25,17 @@ semantics against the committed fixture manifest on CPU, proving the
 manifest record→replay→check loop stays green), the chaos smoke
 (`gmtpu chaos --check` semantics replaying scripts/chaos_smoke_plan.json
 against a tiny serve workload, proving the fault-injection + recovery
-fabric invariants — docs/ROBUSTNESS.md), the telemetry smoke (a
+fabric invariants — docs/ROBUSTNESS.md), and the telemetry smoke (a
 traced serve workload whose /metrics scrape must parse and whose
-dispatch-gap report must be non-empty — docs/OBSERVABILITY.md), and
-the lane smoke (the vmapped-lane
-vs fused-slot standing-query comparison at S=256 with membership churn:
->=10x events/s floor, identical event totals, lane dispatches/poll <=4
-— docs/SERVING.md "Standing queries"). Rides the tier-1 pytest run via
-tests/test_lint_gate.py and is runnable standalone:
+dispatch-gap report must be non-empty — docs/OBSERVABILITY.md).
+Rides the tier-1 pytest run via tests/test_lint_gate.py and is
+runnable standalone:
 
     python scripts/lint_gate.py [--format json|sarif]
         [--no-spmd-smoke] [--no-dataflow-smoke] [--no-warmup-smoke]
         [--no-chaos-smoke] [--no-telemetry-smoke] [--no-fleet-smoke]
         [--no-rehome-smoke] [--no-approx-smoke]
-        [--no-wire-smoke] [--no-ring-smoke] [--no-lane-smoke]
+        [--no-wire-smoke]
 
 Rule catalog + waiver syntax: docs/ANALYSIS.md.
 """
@@ -628,175 +625,6 @@ def wire_smoke() -> int:
     return 1 if failures else 0
 
 
-def ring_smoke() -> int:
-    """The persistent serve loop end to end (docs/SERVING.md
-    "Persistent serve loop"): a small sequential kNN workload through
-    the ring path must (a) serve every window past warmup over ONE
-    armed ring program, (b) answer bit-identical to a serial-path
-    replay of the same queries, and (c) measure dispatches_per_window
-    strictly below an identical ring-off (pipelined) run — the
-    structural form of the dispatch-amortization claim CPU CI can
-    assert. Stderr-only like the other smokes."""
-    _pin_cpu()
-    import tempfile
-
-    import numpy as np
-
-    from geomesa_tpu.core.columnar import FeatureBatch
-    from geomesa_tpu.core.sft import SimpleFeatureType
-    from geomesa_tpu.plan.datastore import DataStore
-    from geomesa_tpu.serve.loadgen import device_ops_count
-    from geomesa_tpu.serve.service import QueryService, ServeConfig
-
-    failures = []
-    rng = np.random.default_rng(23)
-    n = 512
-    windows = 18
-    sft = SimpleFeatureType.from_spec(
-        "ringsmoke", "name:String,dtg:Date,*geom:Point")
-    cql = "BBOX(geom, -180, -90, 180, 90)"
-    with tempfile.TemporaryDirectory() as tmp:
-        store = DataStore(tmp, use_device_cache=True)
-        src = store.create_schema(sft)
-        src.write(FeatureBatch.from_pydict(sft, {
-            "name": rng.choice(["a", "b"], n).tolist(),
-            "dtg": rng.integers(1_590_000_000_000, 1_600_000_000_000, n),
-            "geom": np.stack([rng.uniform(-170, 170, n),
-                              rng.uniform(-80, 80, n)], 1),
-        }))
-        pts = rng.uniform(-60, 60, (windows, 2))
-        planner = store.get_feature_source("ringsmoke").planner
-        from geomesa_tpu.plan.query import Query
-
-        serial = [planner.knn(Query("ringsmoke", cql), pts[i:i + 1, 0],
-                              pts[i:i + 1, 1], k=4)
-                  for i in range(windows)]
-
-        def run(cfg):
-            svc = QueryService(store, cfg)
-            try:
-                # warm pass: arm/compile outside the measured loop
-                for i in range(2):
-                    svc.knn("ringsmoke", cql, pts[i:i + 1, 0],
-                            pts[i:i + 1, 1], k=4).result(timeout=300)
-                o0 = device_ops_count()
-                out = []
-                for i in range(windows):
-                    out.append(svc.knn(
-                        "ringsmoke", cql, pts[i:i + 1, 0],
-                        pts[i:i + 1, 1], k=4).result(timeout=300))
-                per_window = (device_ops_count() - o0) / windows
-                return out, per_window, svc.stats()["pipeline"]
-            finally:
-                svc.close(drain=True)
-
-        ring_res, ring_pw, ring_stats = run(ServeConfig(max_wait_ms=1.0))
-        pipe_res, pipe_pw, _ = run(
-            ServeConfig(max_wait_ms=1.0, ring=False))
-    for i, ((d, ix, _b), (sd, six, _sb)) in enumerate(
-            zip(ring_res, serial)):
-        if not (np.array_equal(d, sd) and np.array_equal(ix, six)):
-            failures.append(f"ring window {i} != serial replay")
-            break
-    for i, ((d, ix, _b), (pd, pix, _pb)) in enumerate(
-            zip(ring_res, pipe_res)):
-        if not (np.array_equal(d, pd) and np.array_equal(ix, pix)):
-            failures.append(f"ring window {i} != pipelined replay")
-            break
-    ring = ring_stats.get("ring") or {}
-    if ring.get("windows", 0) < windows:
-        failures.append(
-            f"only {ring.get('windows')} of {windows} windows rode "
-            f"the ring (fallbacks: {ring.get('fallbacks')})")
-    if not ring_pw < pipe_pw:
-        failures.append(
-            f"dispatches_per_window not below the pipelined baseline: "
-            f"ring {ring_pw} vs pipelined {pipe_pw}")
-    print(
-        f"ring smoke: {ring.get('windows')}/{windows} ring window(s) "
-        f"over {ring.get('armed')} armed program(s), "
-        f"dispatches/window ring={ring_pw:.2f} vs "
-        f"pipelined={pipe_pw:.2f}", file=sys.stderr)
-    for f in failures:
-        print(f"ring smoke: FAIL {f}", file=sys.stderr)
-    return 1 if failures else 0
-
-
-def lane_smoke() -> int:
-    """The vmapped-lane loop (docs/SERVING.md "Standing queries"): the
-    lane-vs-fused-slot comparison at S=256 same-class bbox geofences
-    with one membership-churn event in both measured windows — the
-    lane leg must clear the >=10x events/s floor (the fused leg pays
-    an S-proportional trace+compile on the first poll and a full
-    rebuild on churn; the lane leg one batched kernel + a parameter-
-    row write), lane dispatches-per-poll must stay <=4 (one geofence
-    class => one batched dispatch per poll), and both legs must push
-    the IDENTICAL event total (the speedup is not bought with dropped
-    events). S=256 keeps the fused leg near ~20 s; the S=1024 floor
-    itself rides tier-1 via tests/test_subscribe.py. Stderr-only like
-    the other smokes."""
-    _pin_cpu()
-    import numpy as np
-
-    from geomesa_tpu.core.columnar import FeatureBatch
-    from geomesa_tpu.core.sft import SimpleFeatureType
-    from geomesa_tpu.kafka.store import KafkaDataStore
-    from geomesa_tpu.serve.loadgen import run_subscribe_lanes
-
-    failures = []
-    sft = SimpleFeatureType.from_spec(
-        "lanesmoke", "name:String,score:Double,dtg:Date,*geom:Point")
-    n = 256
-
-    def make_store():
-        store = KafkaDataStore()
-        store.create_schema(sft)
-        return store
-
-    def make_batch(i: int) -> FeatureBatch:
-        rng = np.random.default_rng(997 * i + 13)
-        return FeatureBatch.from_pydict(sft, {
-            "name": rng.choice(["a", "b", "c"], n).tolist(),
-            "score": rng.uniform(-10, 10, n),
-            "dtg": rng.integers(
-                1_590_000_000_000, 1_600_000_000_000, n),
-            "geom": np.stack([rng.uniform(-60, 60, n),
-                              rng.uniform(-30, 30, n)], 1),
-        }, fids=[f"v{j}" for j in range(n)])
-
-    rep = run_subscribe_lanes(make_store, "lanesmoke", make_batch,
-                              subscriptions=256, batches=2)
-    lanes, fused = rep["lanes"], rep["fused"]
-    if lanes["events_total"] != fused["events_total"]:
-        failures.append(
-            f"event totals diverge: lanes {lanes['events_total']} vs "
-            f"fused {fused['events_total']}")
-    if rep.get("speedup", 0.0) < 10.0:
-        failures.append(
-            f"lane events/s floor missed: {rep.get('speedup')}x < 10x "
-            f"(lanes {lanes['events_per_s']}/s vs fused "
-            f"{fused['events_per_s']}/s)")
-    if lanes["dispatches_per_poll"] > 4.0:
-        failures.append(
-            f"lane dispatches-per-poll {lanes['dispatches_per_poll']} "
-            f"> 4 for one geofence class")
-    if lanes["lane_dispatches"] < lanes["polls"]:
-        failures.append(
-            f"lane path not exercised: {lanes['lane_dispatches']} lane "
-            f"dispatch(es) over {lanes['polls']} poll(s)")
-    print(
-        f"lane smoke: S=256 speedup {rep.get('speedup')}x "
-        f"(lanes first_poll {lanes['first_poll_s']}s churn "
-        f"{lanes.get('churn_poll_s')}s vs fused {fused['first_poll_s']}s"
-        f"/{fused.get('churn_poll_s')}s), "
-        f"{lanes['events_total']} event(s) both legs, lane "
-        f"dispatches/poll {lanes['dispatches_per_poll']}",
-        file=sys.stderr)
-    for f in failures:
-        print(f"lane smoke: FAIL {f}", file=sys.stderr)
-    return 1 if failures else 0
-
-
 def spmd_smoke() -> int:
     """Prove the SPMD pass still bites: lint a known-dirty fixture — a
     miniature repo skeleton (pyproject.toml + geomesa_tpu/parallel/
@@ -973,8 +801,7 @@ def warmup_smoke(manifest_path: str = SMOKE_MANIFEST) -> int:
     CPU (the fixture records interpret-mode kernels; this gate must run
     on hardware-less CI). Output goes to stderr only — stdout stays
     machine-parseable for the lint formats. Returns 0 on pass."""
-    # same backend pinning as bench.py --smoke; the "tpu" factory must
-    # stay registered for pallas lowering imports
+    # the "tpu" factory must stay registered for pallas lowering imports
     _pin_cpu()
 
     from geomesa_tpu.compilecache.manifest import WarmupManifest
@@ -1047,18 +874,6 @@ def main(argv=None) -> int:
                         "columnar session with decoded parity vs a "
                         "JSON replay + one-encode push fan-out to 64 "
                         "in-process subscribers; text mode only)")
-    p.add_argument("--no-ring-smoke", action="store_true",
-                   help="skip the persistent-serve-loop smoke "
-                        "(sequential kNN windows over one armed ring "
-                        "program: bit-identity vs serial + "
-                        "dispatches_per_window strictly below the "
-                        "pipelined baseline; text mode only)")
-    p.add_argument("--no-lane-smoke", action="store_true",
-                   help="skip the vmapped-lane smoke (lane vs fused-"
-                        "slot standing-query comparison at S=256 with "
-                        "membership churn: >=10x events/s floor, "
-                        "identical event totals, lane dispatches/poll "
-                        "<=4; text mode only)")
     args = p.parse_args(argv)
     # incremental: a warm cache replays findings byte-identical to a
     # cold scan (asserted by tests/test_analysis_spmd.py), so repeated
@@ -1091,10 +906,6 @@ def main(argv=None) -> int:
         rc = approx_smoke()
     if args.format == "text" and not args.no_wire_smoke and rc == 0:
         rc = wire_smoke()
-    if args.format == "text" and not args.no_ring_smoke and rc == 0:
-        rc = ring_smoke()
-    if args.format == "text" and not args.no_lane_smoke and rc == 0:
-        rc = lane_smoke()
     return rc
 
 

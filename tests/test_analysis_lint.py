@@ -601,7 +601,7 @@ class TestGT15TelemetryDiscipline:
         # plan/ keeps its perf_counter discipline via other means; the
         # wall-clock audit timestamps there are deliberate
         assert self._findings(self.DIRTY, "geomesa_tpu/plan/mod.py") == []
-        assert self._findings(self.DIRTY, "bench.py") == []
+        assert self._findings(self.DIRTY, "scripts/report.py") == []
         assert self._findings(
             self.DIRTY, "geomesa_tpu/engine/mod.py")
         assert self._findings(
@@ -1428,7 +1428,7 @@ class TestGT22PerRowWireEncode:
         assert self._findings(
             self.DIRTY, "geomesa_tpu/subscribe/manager.py") != []
         assert self._findings(
-            self.DIRTY, "geomesa_tpu/serve/loadgen.py") != []
+            self.DIRTY, "geomesa_tpu/serve/columnar.py") != []
 
     def test_registration(self):
         from geomesa_tpu.analysis.model import RULES

@@ -268,9 +268,7 @@ class TestParity:
 
     def test_sketch_p50_speedup_over_exact(self, wide_store):
         """The headline: warm tolerant counts vs warm exact device
-        counts — asserted at a conservative 25x (measured >100x on CI
-        hardware; ISSUE acceptance is 50x, reported by bench-serve
-        --mode approx)."""
+        counts — asserted at a conservative 25x."""
         pl = wide_store.get_feature_source("apx").planner
         qa = Query("apx", CQLS[1], hints=QueryHints(tolerance=0.25))
         qe = Query("apx", CQLS[1])

@@ -36,3 +36,14 @@ def test_phases_match_host_oracle(tmp_path, seed):
     direct = chip_smoke.direct_phase(src, oracle, qx, qy)
     served = chip_smoke.serve_phase(store, qx, qy, direct)
     assert served["dispatches"] >= 1
+
+
+@pytest.mark.parametrize("seed", [97, 98])
+def test_kernel_gates_match_oracles(seed):
+    # the chip's 2^20 rows, 2^15 points and 256^2 grid cut to a size
+    # the interpreted kernels run in seconds; 32^2 cells keep a
+    # z-ordered tile under the sparse density kernel's cell cap
+    gates = chip_smoke.kernel_gates(rows=1 << 14, pip_points=1 << 11,
+                                    grid=32, seed=seed)
+    assert gates == {"pip_layer": True, "pip_assign": True,
+                     "density_zsparse": True, "tube_pruned": True}

@@ -565,7 +565,7 @@ class TestGT13:
 
     def test_engine_and_elsewhere_out_of_scope(self):
         assert self._findings(self.SRC, "geomesa_tpu/engine/kernel.py") == []
-        assert self._findings(self.SRC, "bench.py") == []
+        assert self._findings(self.SRC, "scripts/report.py") == []
 
     def test_from_import_alias_decorator(self):
         src = ("from jax import jit\n"

@@ -373,12 +373,21 @@ class TestBBoxBandExactCount:
         compiled = compile_filter(f, sft)
         assert compiled.has_band  # bbox filters now carry a band
         dev = to_device(batch, coord_dtype=jnp.float32)
-        got = compiled.count_exact(dev, batch)
+
+        def count_exact(extra=None):
+            # the planner's count: device sum + the f64 band correction
+            m = compiled.mask(dev, batch)
+            if extra is not None:
+                m = m & extra
+            return (int(jnp.sum(m, dtype=jnp.int64))
+                    + compiled.band_count_correction(dev, batch, m, extra))
+
+        got = count_exact()
         exp = int(np.sum((x >= -60) & (x <= 60) & (y >= -30) & (y <= 30)))
         assert got == exp
         # extra mask participates in both count and correction
         extra = jnp.asarray(np.arange(len(batch)) % 2 == 0)
-        got_e = compiled.count_exact(dev, batch, extra=extra)
+        got_e = count_exact(extra)
         exp_e = int(np.sum((x >= -60) & (x <= 60) & (y >= -30) & (y <= 30)
                            & (np.arange(len(batch)) % 2 == 0)))
         assert got_e == exp_e

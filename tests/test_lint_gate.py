@@ -13,8 +13,8 @@ GATE = os.path.join(REPO_ROOT, "scripts", "lint_gate.py")
 
 def test_lint_gate_passes_on_shipped_tree():
     # --no-spmd-smoke / --no-dataflow-smoke / --no-chaos-smoke /
-    # --no-telemetry-smoke / --no-fleet-smoke / --no-wire-smoke /
-    # --no-ring-smoke: those invariants already run in-process in this
+    # --no-telemetry-smoke / --no-fleet-smoke / --no-wire-smoke:
+    # those invariants already run in-process in this
     # same tier-1 suite (tests/test_analysis_spmd.py dirty-fixture
     # replays for every SPMD rule; tests/test_analysis_dataflow.py
     # dirty/clean fixtures for every dataflow rule plus the SARIF
@@ -22,9 +22,7 @@ def test_lint_gate_passes_on_shipped_tree():
     # regression; tests/test_telemetry.py trace/scrape/gap checks;
     # tests/test_fleet.py kill-mid-burst failover + subscription
     # re-home across an owner kill (TestRehome); tests/test_wire.py
-    # columnar parity + one-encode fan-out; tests/test_ringloop.py ring
-    # bit-identity + dispatches_per_window; tests/test_subscribe.py
-    # lane-vs-fused floor + parity); repeating them in a cold
+    # columnar parity + one-encode fan-out); repeating them in a cold
     # subprocess would only re-pay jax startup + kernel compiles
     # against the suite's wall-clock budget. All smokes still guard
     # standalone `python scripts/lint_gate.py` CI runs.
@@ -32,8 +30,7 @@ def test_lint_gate_passes_on_shipped_tree():
                         "--no-dataflow-smoke", "--no-chaos-smoke",
                         "--no-telemetry-smoke", "--no-fleet-smoke",
                         "--no-rehome-smoke", "--no-approx-smoke",
-                        "--no-wire-smoke", "--no-ring-smoke",
-                        "--no-lane-smoke"],
+                        "--no-wire-smoke"],
                        capture_output=True, text=True, cwd=REPO_ROOT)
     assert r.returncode == 0, (
         f"lint gate failed:\n{r.stdout}\n{r.stderr}")

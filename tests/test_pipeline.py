@@ -289,24 +289,6 @@ class TestPipelineIdentity:
         np.testing.assert_array_equal(d1, d2)
         np.testing.assert_array_equal(i1, i2)
 
-    def test_sustained_loadgen_reports_pipeline_depth(self, store):
-        from geomesa_tpu.serve import knn_request_factory, run_sustained
-
-        svc = QueryService(store, ServeConfig(max_wait_ms=1.0))
-        try:
-            rep = run_sustained(
-                svc, knn_request_factory("served", CQL, k=5),
-                duration_s=30.0, max_outstanding=8,
-                points_per_query=600, requests=12)
-        finally:
-            svc.close(drain=True)
-        assert rep.mode == "sustained"
-        assert rep.ok == 12 and rep.errors == 0
-        assert rep.pts_per_s > 0
-        assert rep.pipelined_windows >= 1
-        assert rep.windows_in_flight_max >= 1
-        assert rep.to_json()["pts_per_s"] == rep.pts_per_s
-
 
 # -- gap report on synthetic pipelined spans --------------------------------
 

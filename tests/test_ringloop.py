@@ -32,7 +32,6 @@ from geomesa_tpu.core.sft import SimpleFeatureType
 from geomesa_tpu.plan.datastore import DataStore
 from geomesa_tpu.plan.query import Query
 from geomesa_tpu.serve import QueryService, ServeConfig
-from geomesa_tpu.serve.loadgen import device_ops_count
 
 CQL = "BBOX(geom, -170, -80, 170, 80) AND score > -5"
 WINDOWS = 18  # >= 16 consecutive ring windows (acceptance floor)
@@ -178,33 +177,15 @@ class TestRingIdentity:
             svc = QueryService(store, config)
             try:
                 _sequential(store, qpts, None, lo=0, hi=2, svc=svc)
-                o0 = device_ops_count()
+                o0 = _counter("serve.device.ops")
                 _sequential(store, qpts, None, lo=2, hi=WINDOWS, svc=svc)
-                return (device_ops_count() - o0) / (WINDOWS - 2)
+                return (_counter("serve.device.ops") - o0) / (WINDOWS - 2)
             finally:
                 svc.close(drain=True)
 
         ring_pw = measured(ServeConfig(max_wait_ms=1.0))
         pipe_pw = measured(ServeConfig(max_wait_ms=1.0, ring=False))
         assert ring_pw < pipe_pw, (ring_pw, pipe_pw)
-
-    def test_sustained_loadgen_reports_ring_fields(self, store):
-        from geomesa_tpu.serve import knn_request_factory, run_sustained
-
-        svc = QueryService(store, ServeConfig(max_wait_ms=1.0))
-        try:
-            rep = run_sustained(
-                svc, knn_request_factory("served", CQL, k=5),
-                duration_s=30.0, max_outstanding=4,
-                points_per_query=600, requests=10)
-        finally:
-            svc.close(drain=True)
-        assert rep.ok == 10 and rep.errors == 0
-        assert rep.ring_windows >= 1
-        assert rep.dispatches_per_window > 0
-        doc = rep.to_json()
-        assert doc["ring_windows"] == rep.ring_windows
-        assert doc["dispatches_per_window"] == rep.dispatches_per_window
 
 
 class TestRingFallbacks:

@@ -949,30 +949,56 @@ class TestLanes:
         the first (compiling) poll inside the measured window — the
         fused slot path pays an S-proportional trace+compile there,
         the lane path one S-independent batched kernel. Churn is
-        excluded HERE only to keep the fused leg to a single compile
-        inside the tier-1 budget; the churn-inclusive comparison runs
-        in scripts/lint_gate.py lane_smoke and the zero-recompile
-        churn contract is JitTracker-asserted above."""
-        from geomesa_tpu.serve.loadgen import run_subscribe_lanes
-
-        def make_store():
-            store = KafkaDataStore()
-            store.create_schema(SFT)
-            return store
-
+        excluded to keep the fused leg to a single compile inside the
+        tier-1 budget; the zero-recompile churn contract is
+        JitTracker-asserted above."""
+        S = 1024
         fids = [f"f{i}" for i in range(N_FIDS)]
 
-        def make_batch(i):
-            return _rows(600 + i, fids)
+        def leg(lanes: bool) -> dict:
+            # S same-class bbox geofences registered BEFORE the seed
+            # batch lands, then the first poll and one steady poll
+            store = KafkaDataStore()
+            store.create_schema(SFT)
+            mgr = SubscriptionManager(store, SubscribeConfig(
+                max_subscriptions=S + 8, lanes=lanes))
+            rng = np.random.default_rng(5)
+            for _ in range(S):
+                x0 = float(rng.uniform(-60.0, 28.0))
+                y0 = float(rng.uniform(-30.0, 9.0))
+                mgr.subscribe(
+                    "live", f"BBOX(geom, {x0}, {y0}, {x0 + 2}, {y0 + 2})")
+            store.write("live", _rows(600 + 10_001, fids))
+            frames = []
+            base = mgr.evaluator.stats()
+            t0 = time.monotonic()
+            mgr.poll_now()
+            mgr.flush(frames.append)
+            store.write("live", _rows(600, fids))
+            mgr.poll_now()
+            mgr.flush(frames.append)
+            wall = time.monotonic() - t0
+            ev = mgr.evaluator.stats()
+            mgr.close()
+            events = sum(len(f.get("fids", ())) for f in frames
+                         if f.get("event") in ("enter", "exit"))
+            polls = 2
+            return {
+                "polls": polls, "events_total": events,
+                "events_per_s": events / wall,
+                "dispatches_per_poll": (
+                    ev.get("dispatches", 0)
+                    - base.get("dispatches", 0)) / polls,
+                "lane_dispatches": ev.get("lane_dispatches", 0)
+                - base.get("lane_dispatches", 0),
+            }
 
-        rep = run_subscribe_lanes(make_store, "live", make_batch,
-                                  subscriptions=1024, batches=1,
-                                  churn=False)
-        lanes, fused = rep["lanes"], rep["fused"]
+        lanes, fused = leg(True), leg(False)
         # the speedup must not be bought with dropped events
         assert lanes["events_total"] == fused["events_total"] > 0
-        assert rep["speedup"] >= 10.0, (
-            f"lane floor missed: {rep['speedup']}x "
+        speedup = lanes["events_per_s"] / fused["events_per_s"]
+        assert speedup >= 10.0, (
+            f"lane floor missed: {speedup}x "
             f"(lanes {lanes['events_per_s']}/s vs fused "
             f"{fused['events_per_s']}/s)")
         assert lanes["dispatches_per_poll"] <= 4.0
@@ -1099,37 +1125,3 @@ class TestHandoff:
         state = [d for d in docs_b if d.get("event") == "state"]
         assert state and state[0]["seq"] == snap["watermark"] + 1
         assert set(state[0]["fids"]) == set(snap["matched"])
-
-
-class TestLoadgen:
-    def test_run_subscribe_reports(self):
-        from geomesa_tpu.serve.loadgen import run_subscribe
-
-        store = KafkaDataStore()
-        store.create_schema(SFT)
-        fids = [f"f{i}" for i in range(24)]
-
-        def make_batch(i):
-            return _rows(700 + i, fids)
-
-        rep = run_subscribe(store, "live", make_batch,
-                            subscriptions=3, batches=4)
-        assert rep.mode == "subscribe"
-        assert rep.subscriptions == 3 and rep.batches == 4
-        assert rep.events_total > 0 and rep.events_per_s > 0
-        # three dispatches per folded batch: the 3 cycling subscription
-        # kinds land one each in the bbox lane, the dwithin lane and
-        # the fused remainder (the density window)
-        assert rep.dispatches == 12
-        assert rep.p99_ms >= rep.p50_ms >= 0
-        # a caller-owned manager gets its bench subscriptions cancelled
-        # at return (repeated comparison runs must not accumulate 8
-        # stale subs each until the table bound rejects the run)
-        from geomesa_tpu.subscribe import SubscriptionManager
-        mgr = SubscriptionManager(store)
-        try:
-            run_subscribe(store, "live", make_batch,
-                          subscriptions=3, batches=2, manager=mgr)
-            assert len(mgr.registry) == 0
-        finally:
-            mgr.close()

@@ -534,18 +534,59 @@ class TestSubscribeFanout:
 
 class TestThroughputFloor:
     def test_columnar_5x_json_at_20k_rows(self, tmp_path):
-        from geomesa_tpu.serve.loadgen import run_wire
+        """One bulk `execute` result encoded both ways over identical
+        rows: the columnar frame decodes to the JSON rows and encodes
+        >=5x the rows/s; a PushMux fan-out encodes each frame once."""
+        from geomesa_tpu.plan.query import Query
+        from geomesa_tpu.serve.protocol import _payload
 
-        sft, batch = make_batch(n=20_000, seed=7, with_nulls=False)
+        rows = 20_000
+        sft, batch = make_batch(n=rows, seed=7, with_nulls=False)
         ds = DataStore(str(tmp_path / "tp"), use_device_cache=True)
         ds.create_schema(sft).write(batch)
-        rep = run_wire(ds, "served", rows=20_000, iters_json=2,
-                       iters_columnar=4, push_sinks=32, push_frames=10)
-        assert rep.wire_parity_ok
-        # acceptance floor (>=5x); measured ~40-200x on CPU CI
-        assert rep.wire_speedup >= 5.0, rep.wire_speedup
-        assert rep.push_encodes == rep.push_frames
-        assert rep.wire_rows == 20_000
+        result = ds.get_feature_source("served").planner.execute(
+            Query("served", "INCLUDE", max_features=rows))
+        assert len(result.features) == rows
+
+        def one_json():
+            t0 = time.monotonic()
+            doc = {"id": "b", "ok": True}
+            doc.update(_payload("execute", result, rows))
+            buf = (json.dumps(doc) + "\n").encode()
+            return buf, time.monotonic() - t0
+
+        def one_columnar():
+            t0 = time.monotonic()
+            fields, payload = colwire.encode_execute_frame(
+                result.features, rows)
+            doc = {"id": "b", "ok": True, "kind": "features",
+                   "count": rows, "frame": fields}
+            return colwire.frame_bytes(doc, payload), time.monotonic() - t0
+
+        # parity before timing: a fast encoder that decodes wrong is
+        # not a result
+        jbuf, _ = one_json()
+        cbuf, _ = one_columnar()
+        (_cdoc, cpayload), = colwire.parse_stream(cbuf)
+        assert (colwire.decode_execute_payload(cpayload)
+                == json.loads(jbuf.decode())["features"])
+        j_s = np.median([one_json()[1] for _ in range(2)])
+        c_s = np.median([one_columnar()[1] for _ in range(4)])
+        speedup = j_s / c_s
+        # acceptance floor (>=5x)
+        assert speedup >= 5.0, speedup
+
+        frames = 10
+        mux = colwire.PushMux(queue_limit=frames + 8)
+        sinks = [mux.register(lambda _buf: None, mode=colwire.WIRE_JSON,
+                              threaded=False) for _ in range(32)]
+        fids = [f"f{i}" for i in range(64)]
+        for i in range(frames):
+            mux.publish({"event": "enter", "subscription": "s",
+                         "seq": i + 1, "fids": fids}, sinks)
+        encodes = mux.stats()["encodes"]
+        mux.close()
+        assert encodes == frames
 
 
 class TestReplicaSocketTransport:
