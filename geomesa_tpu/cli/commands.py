@@ -140,8 +140,11 @@ def register(sub: "argparse._SubParsersAction") -> None:
                          help="admission queue bound (backpressure)")
     serve_p.add_argument("--max-batch", type=int, default=64,
                          help="coalescing cap per device dispatch")
-    serve_p.add_argument("--max-wait-ms", type=float, default=2.0,
-                         help="coalescing window (added-latency ceiling)")
+    serve_p.add_argument("--max-wait-ms", type=float, default=None,
+                         help="fixed coalescing window (added-latency "
+                              "ceiling); default: end the gather when "
+                              "nothing compatible is queued and the "
+                              "route can start the window")
     serve_p.add_argument("--timeout-ms", type=int, default=None,
                          help="default per-request deadline")
     serve_p.add_argument("--tenant-rate", type=float, default=None,

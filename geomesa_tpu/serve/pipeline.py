@@ -126,6 +126,9 @@ class DispatchPipeline:
         self._slots = threading.BoundedSemaphore(self.depth)
         self._completions: SimpleQueue = SimpleQueue()
         self._lock = threading.Lock()
+        # notified when a window gives its slot back, for a coalescing
+        # gather waiting on a full pipeline (QueryService._gather)
+        self._slot_freed = threading.Condition(self._lock)
         self._seq = 0
         self._inflight = 0
         self._max_inflight = 0
@@ -523,7 +526,29 @@ class DispatchPipeline:
                              window=win.wid)
             with self._lock:
                 self._inflight -= 1
+                self._slot_freed.notify_all()
             self._slots.release()
+
+    # -- slot state, read by the coalescing gather ---------------------------
+
+    def slot_free(self) -> bool:
+        """Could a window start now? A peek at the windows in flight;
+        the semaphore is left to submit."""
+        with self._lock:
+            return self._inflight < self.depth
+
+    def wait_slot(self, timeout_s: float = 1.0) -> bool:
+        """Block until a window gives its slot back. False when none
+        did within `timeout_s` and the completer is not running, so
+        none ever will: the caller then goes on to submit, whose own
+        guard fails the window loudly."""
+        with self._lock:
+            while self._inflight >= self.depth:
+                if not self._slot_freed.wait(timeout_s):
+                    worker = self._worker
+                    if worker is None or not worker.is_alive():
+                        return False
+            return True
 
     # -- introspection -----------------------------------------------------
 

@@ -33,7 +33,7 @@ import dataclasses
 import threading
 import time
 from concurrent.futures import Future
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -54,7 +54,12 @@ from geomesa_tpu.utils.padding import next_pow2 as _next_pow2
 class ServeConfig:
     max_queue: int = 128        # admission bound (backpressure, not buffer)
     max_batch: int = 64         # coalescing cap per dispatch
-    max_wait_ms: float = 2.0    # coalescing window: added latency ceiling
+    # coalescing window. None (the default): the gather ends as soon as
+    # no compatible request is queued and the window's route can start
+    # it (a free pipeline slot; a serial window always can), so windows
+    # grow only while the device pipeline is behind. A number: wait out
+    # that fixed window (the added-latency ceiling), 0 = no wait
+    max_wait_ms: Optional[float] = None
     default_timeout_ms: Optional[int] = None  # per-request deadline default
     tenant_rate: Optional[float] = None  # qps per tenant; None = unlimited
     tenant_burst: float = 8.0
@@ -155,6 +160,11 @@ class ServeConfig:
     # dashboard queries cost a dict lookup, invalidation is exact by
     # construction (a write bumps the version). 0 disables.
     result_cache: int = 256
+
+
+# the ways a coalescing gather ends (`serve.coalesce.<end>` counters,
+# the `coalesce` span's `ended` attribute)
+COALESCE_ENDS = ("immediate", "slot_bound", "timed", "cap")
 
 
 def _quarantine_key(req: ServeRequest):
@@ -869,26 +879,47 @@ class QueryService:
                 with self._state_lock:
                     self._inflight -= 1
 
-    def _gather(self, first: ServeRequest) -> List[ServeRequest]:
+    def _gather(self, first: ServeRequest) -> Tuple[List[ServeRequest], str]:
         """Coalescing window: collect queued requests compatible with
-        `first` for up to max_wait_ms (bounded added latency), then go."""
+        `first`, up to max_batch. Returns the members and how the
+        gather ended (COALESCE_ENDS): `cap` (max_batch filled),
+        `timed` (an explicit max_wait_ms ran out), `immediate` (nothing
+        compatible queued and the route free: no wait at all) or
+        `slot_bound` (every pipeline slot was busy; the window took
+        what arrived until one freed)."""
         reqs = [first]
         key = compat_key(first)
         cap = self.config.max_batch
-        if key is None or cap <= 1:
-            return reqs
+        if cap <= 1:
+            return reqs, "cap"
+        if key is None:
+            return reqs, "immediate"
+        if self.config.max_wait_ms is None:
+            # only a pipelined window can find its route busy; a serial
+            # one runs on this thread, so it can always start now
+            pipe = self.pipeline if first.kind == "knn" else None
+            ended = "immediate"
+            while True:
+                reqs.extend(self.queue.drain_compatible(
+                    key, compat_key, cap - len(reqs)))
+                if len(reqs) >= cap:
+                    return reqs, "cap"
+                if pipe is None or pipe.slot_free():
+                    return reqs, ended
+                ended = "slot_bound"
+                if not pipe.wait_slot():
+                    return reqs, ended
         deadline = time.monotonic() + self.config.max_wait_ms / 1000.0
-        while len(reqs) < cap:
+        while True:
             got = self.queue.drain_compatible(
                 key, compat_key, cap - len(reqs))
             reqs.extend(got)
             if len(reqs) >= cap:
-                break
+                return reqs, "cap"
             remaining = deadline - time.monotonic()
             if remaining <= 0:
-                break
+                return reqs, "timed"
             time.sleep(min(0.0005, remaining))
-        return reqs
 
     def _run_window(self, live: List[ServeRequest]) -> None:
         """The device-facing part of one dispatch: source lookup +
@@ -910,8 +941,10 @@ class QueryService:
         from geomesa_tpu.utils.metrics import metrics
 
         g0_ns = time.perf_counter_ns()
-        reqs = self._gather(first)
+        reqs, ended = self._gather(first)
         g1_ns = time.perf_counter_ns()
+        self._bump("coalesce." + ended)
+        metrics.counter("serve.coalesce." + ended)
         live, dead = split_expired(reqs)
         lead = live[0] if live else None
         pipelined = (self.pipeline is not None and lead is not None
@@ -971,7 +1004,8 @@ class QueryService:
                       if lead.trace is not None else 0)
         if lead.trace is not None:
             lead.trace.record("coalesce", g0_ns, g1_ns,
-                              gathered=len(reqs), fused=len(counts))
+                              gathered=len(reqs), fused=len(counts),
+                              ended=ended)
         if self._recorder is not None:
             self._record_queries(live, counts)
         from geomesa_tpu.faults import RECOVERY
@@ -1287,6 +1321,9 @@ class QueryService:
             out = dict(self._counters)
         out.setdefault("dispatches", 0)
         out.setdefault("coalesced", 0)
+        # how each coalescing gather ended (QueryService._gather)
+        out["coalesce"] = {e: out.pop("coalesce." + e, 0)
+                           for e in COALESCE_ENDS}
         # dispatcher load: busy share over an interval is
         # 1 - Δloop_wait_ns / Δloop_ns
         out["loop_ns"] = self._loop_ns

@@ -667,3 +667,106 @@ class TestDispatcherLoad:
         svc.close(drain=True)
         busy = dispatch_busy_pct(st0, st1)
         assert busy is not None and busy > 50.0, (st0, st1)
+
+
+class TestCoalesceEnds:
+    """The default gather (max_wait_ms None) waits only while every
+    pipeline slot is busy; an explicit max_wait_ms keeps its fixed
+    window."""
+
+    def test_arrivals_ride_the_window_waiting_for_a_slot(self, store):
+        import time as _t
+
+        from geomesa_tpu.utils.metrics import metrics
+
+        rng = np.random.default_rng(7)
+        qpts = rng.uniform(-60, 60, (5, 2))
+        before = metrics.count("serve.coalesce.slot_bound")
+        svc = QueryService(store, ServeConfig(pipeline_depth=2))
+        pipe = svc.pipeline
+        release = threading.Event()
+        real_sync = pipe._sync
+
+        def gated_sync(win):
+            assert release.wait(timeout=60), "test gate never opened"
+            real_sync(win)
+
+        waiting = threading.Event()
+        real_wait = pipe.wait_slot
+
+        def spy_wait(*a, **kw):
+            waiting.set()
+            return real_wait(*a, **kw)
+
+        pipe._sync = gated_sync
+        pipe.wait_slot = spy_wait
+
+        def submit(i):
+            return svc.knn("served", CQL, qpts[i:i + 1, 0],
+                           qpts[i:i + 1, 1], k=5)
+
+        def until(cond):
+            deadline = _t.monotonic() + 60
+            while not cond():
+                assert _t.monotonic() < deadline
+                _t.sleep(0.001)
+
+        try:
+            futs = []
+            for i in range(2):
+                # one window per request: each finds a free slot
+                futs.append(submit(i))
+                until(lambda: pipe.stats()["inflight"] == i + 1)
+            assert not pipe.slot_free()
+            futs += [submit(i) for i in range(2, 5)]
+            assert waiting.wait(timeout=60)
+        finally:
+            release.set()
+        res = [f.result(timeout=120) for f in futs]
+        svc.close(drain=True)
+        st = svc.stats()
+        assert st["coalesce"] == {
+            "immediate": 2, "slot_bound": 1, "timed": 0, "cap": 0}, st
+        assert st["dispatches"] == 3 and st["coalesced"] == 2, st
+        assert metrics.count("serve.coalesce.slot_bound") == before + 1
+        serial, _, _ = _run(store, qpts, ServeConfig(
+            max_wait_ms=0.0, max_batch=1, pipeline=False), counts=0)
+        for i, ((d, ix, _), (sd, six, _)) in enumerate(zip(res, serial)):
+            np.testing.assert_array_equal(d, sd, err_msg=f"knn {i}")
+            np.testing.assert_array_equal(ix, six, err_msg=f"knn {i}")
+
+    def test_wait_slot_gives_up_without_a_completer(self):
+        svc = fake_service([], [], pipeline_depth=2)
+        pipe = svc.pipeline
+        assert pipe.slot_free() and pipe.wait_slot(timeout_s=0.01)
+        pipe._inflight = pipe.depth  # slots held, no completer running
+        assert not pipe.slot_free()
+        assert pipe.wait_slot(timeout_s=0.01) is False
+        pipe._inflight = 0
+
+    def test_explicit_wait_is_a_fixed_window(self):
+        import time as _t
+
+        gates = [threading.Event()]
+        gates[0].set()
+        svc = fake_service([], gates, max_wait_ms=500.0, max_batch=8)
+        svc.start()
+        t0 = _t.monotonic()
+        first = svc.knn("t", "BBOX(geom, 0, 0, 1, 1)", np.array([0.0]),
+                        np.array([0.0]), k=5)
+        # the dispatcher has popped `first` and waits out its window
+        deadline = t0 + 10
+        while len(svc.queue):
+            assert _t.monotonic() < deadline
+            _t.sleep(0.001)
+        _t.sleep(0.02)
+        rider = svc.knn("t", "BBOX(geom, 0, 0, 1, 1)", np.array([1.0]),
+                        np.array([1.0]), k=5)
+        first.result(timeout=30)
+        rider.result(timeout=30)
+        elapsed = _t.monotonic() - t0
+        svc.close(drain=True)
+        st = svc.stats()
+        assert st["dispatches"] == 1 and st["coalesced"] == 1, st
+        assert st["coalesce"]["timed"] == 1, st
+        assert elapsed >= 0.5

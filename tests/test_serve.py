@@ -399,6 +399,60 @@ class TestService:
                 assert f"{family}_{q} " in prom
 
 
+class TestCoalesceEnds:
+    """With no max_wait_ms (the default) a gather waits only while the
+    window's route cannot take it; the pipelined slot-bound case is in
+    tests/test_pipeline.py."""
+
+    def test_lone_knn_dispatches_without_sleeping(self, store,
+                                                  monkeypatch):
+        import geomesa_tpu.serve.service as service_mod
+
+        real_sleep = time.sleep
+        slept = []
+
+        def spy(secs):
+            if threading.current_thread().name == "gmtpu-serve-dispatch":
+                slept.append(secs)
+            real_sleep(secs)
+
+        monkeypatch.setattr(service_mod.time, "sleep", spy)
+        before = metrics.count("serve.coalesce.immediate")
+        svc = QueryService(store)
+        assert svc.config.max_wait_ms is None
+        d, ix, _ = svc.knn("served", CQL, np.array([1.0]),
+                           np.array([2.0]), k=5).result(timeout=120)
+        svc.close(drain=True)
+        assert d.shape == (1, 5) and ix.shape == (1, 5)
+        assert slept == []
+        assert svc.stats()["coalesce"] == {
+            "immediate": 1, "slot_bound": 0, "timed": 0, "cap": 0}
+        assert metrics.count("serve.coalesce.immediate") == before + 1
+
+    def test_default_serial_count_window_coalesces_twins(self, store):
+        before = metrics.count("serve.coalesce.immediate")
+        svc = QueryService(store, autostart=False)
+        futs = [svc.count("served", "score > 2") for _ in range(5)]
+        svc.start()
+        counts = [f.result(timeout=120) for f in futs]
+        svc.close(drain=True)
+        st = svc.stats()
+        assert len(set(counts)) == 1
+        assert st["dispatches"] == 1 and st["coalesced"] == 4
+        assert st["coalesce"]["immediate"] == 1
+        assert metrics.count("serve.coalesce.immediate") == before + 1
+
+    def test_max_batch_one_ends_at_cap(self, store):
+        svc = QueryService(store, ServeConfig(max_batch=1),
+                           autostart=False)
+        futs = [svc.count("served", "score > 3") for _ in range(2)]
+        svc.start()
+        for f in futs:
+            f.result(timeout=120)
+        svc.close(drain=True)
+        assert svc.stats()["coalesce"]["cap"] == 2
+
+
 # -- JSON-lines protocol + CLI ---------------------------------------------
 
 
